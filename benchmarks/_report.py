@@ -37,6 +37,17 @@ ensure_import_paths()
 LAT_KEYS = ("ttft_p50", "ttft_p95", "tpot_p50", "tpot_p95", "gap_p95", "e2e_p95")
 
 
+def parse_cli(ap: argparse.ArgumentParser,
+              argv: Optional[Sequence[str]] = None) -> argparse.Namespace:
+    """Every benchmark's entry: parse its arguments and turn on JAX's
+    persistent compilation cache (``repro.launch.compile_cache``)."""
+    from repro.launch.compile_cache import enable_compile_cache
+
+    args = ap.parse_args(argv)
+    enable_compile_cache()
+    return args
+
+
 def smoke_flag(description: str = "", argv: Optional[Sequence[str]] = None) -> bool:
     """Uniform benchmark CLI: ``--smoke`` runs the tiny configuration (CI
     executes every benchmark this way)."""
@@ -45,7 +56,7 @@ def smoke_flag(description: str = "", argv: Optional[Sequence[str]] = None) -> b
         "--smoke", action="store_true",
         help="tiny model / few requests: fast smoke run for CI",
     )
-    return ap.parse_args(argv).smoke
+    return parse_cli(ap, argv).smoke
 
 
 def latency_row(summary: Dict[str, float], keys: Sequence[str] = LAT_KEYS) -> Dict[str, float]:

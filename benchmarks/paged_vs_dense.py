@@ -13,7 +13,7 @@ import time
 
 import argparse
 
-from _report import latency_row, print_latency_ms
+from _report import latency_row, parse_cli, print_latency_ms
 
 import jax
 import numpy as np
@@ -156,5 +156,5 @@ if __name__ == "__main__":
     ap.add_argument("--kv-dtype", default=None, choices=["int8"],
                     help="also run the paged engine with quantized KV pools "
                          "and report capacity + greedy-agreement vs float")
-    args = ap.parse_args()
+    args = parse_cli(ap)
     main(smoke=args.smoke, kernel=args.kernel, kv_dtype=args.kv_dtype)

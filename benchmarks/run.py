@@ -20,7 +20,9 @@ def main(argv=None):
     ap.add_argument("--smoke", action="store_true",
                     help="alias for the default fast mode (uniform bench CLI)")
     ap.add_argument("--only", default=None)
-    args = ap.parse_args(argv)
+    from benchmarks._report import parse_cli
+
+    args = parse_cli(ap, argv)
     fast = not args.full or args.smoke
 
     from benchmarks import (

@@ -20,7 +20,7 @@ monolithic/ray-like baselines) instead.
 """
 from __future__ import annotations
 
-from _report import print_table
+from _report import parse_cli, print_table
 
 DT = 0.02           # trace-seconds per engine step (virtual clock)
 SLO_SCALE = 2.0     # deadline = SLO_SCALE x calibrated low-load mean e2e
@@ -156,7 +156,7 @@ if __name__ == "__main__":
     ap.add_argument("--arrival", default="poisson",
                     choices=("poisson", "diurnal", "bursty"))
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    args = parse_cli(ap)
     if args.sim:
         main_sim(fast=args.smoke)
     else:

@@ -34,7 +34,7 @@ import time
 
 import numpy as np
 
-from _report import print_latency_ms, print_table
+from _report import parse_cli, print_latency_ms, print_table
 from paged_vs_dense import greedy_agreement, kv_block_bytes
 
 import jax
@@ -220,5 +220,5 @@ if __name__ == "__main__":
                     help="also run the pressure workload with int8 KV pools "
                          "at the same HBM byte budget: more blocks, fewer "
                          "preemptions, no-worse p95 TTFT (asserted)")
-    args = ap.parse_args()
+    args = parse_cli(ap)
     main(smoke=args.smoke, dp_mesh=args.dp_mesh, kv_dtype=args.kv_dtype)

@@ -217,7 +217,7 @@ def _patch_pool_program(eng, wrap):
 
 def _jx_collective(eng) -> None:
     import jax
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     mesh = Mesh(np.array(jax.devices()[:1]), ("model",))
@@ -226,7 +226,7 @@ def _jx_collective(eng) -> None:
         def bad(k_pool, *rest):
             out, view = jitted(k_pool, *rest)
             # an explicit collective sneaks into the pool roundtrip
-            s = shard_map(lambda a: jax.lax.psum(a, "model"), mesh,
+            s = shard_map(lambda a: jax.lax.psum(a, "model"), mesh=mesh,
                           in_specs=P(), out_specs=P())(view.sum())
             return out + 0 * s.astype(out.dtype), view
         return bad

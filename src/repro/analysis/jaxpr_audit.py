@@ -40,10 +40,7 @@ from typing import Any, Dict, Iterator, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-try:  # jax >= 0.4.33
-    from jax.extend import core as jcore
-except ImportError:  # pragma: no cover - older jax
-    from jax import core as jcore  # type: ignore
+from jax.extend import core as jcore
 
 __all__ = [
     "StepContract", "Finding", "AuditReport", "audit_engine",

@@ -21,8 +21,8 @@ tokens from B sequences (decode rows and prefill chunks mixed in one flat
 buffer) each attend their own sequence's paged KV through the shared block
 table, with the segmented-prompt span mask (prelude + own segment + causal
 self) applied inside the kernel. One query token per grid row keeps the
-q tile at the decode kernel's (G, hd) shape regardless of how the batch is
-packed, so ragged layouts cost no padding FLOPs at all.
+q tile at the decode kernel's (KVH, G, hd) shape regardless of how the
+batch is packed, so ragged layouts cost no padding FLOPs at all.
 
 Both kernels tolerate RAW block tables: pad entries (-1) are masked inside
 the kernel (index_maps clamp them to block 0 purely so the DMA has a legal
@@ -133,59 +133,114 @@ def decode_attention(
 # ---------------------------------------------------------------------------
 # paged (block-table) decode attention
 # ---------------------------------------------------------------------------
+#
+# Mosaic tiling: a VMEM block's last two dims must be multiples of (8, 128)
+# or span the whole array. The pool is (n_blocks, bs, KVH, hd), so a grid
+# cell takes ALL KV heads of one block — a (1, bs, KVH, hd) block whose last
+# two dims are the array's — and loops over the heads in-kernel. Scales are
+# presented as (n_blocks, 1, KVH) for the same reason, and applied to the
+# (G, bs) scores and the (G, hd) value product instead of the (bs, hd) block
+# (a per-(block, head) constant commutes with both dots).
+
+
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _online_softmax_heads(q_ref, k_ref, v_ref, ks_ref, vs_ref, acc_ref,
+                          m_ref, l_ref, valid, *, kvh: int, scale: float):
+    """One KV block's online-softmax update for every KV head of a cell.
+    ``valid`` (1, bs) bool masks this block's slots; q_ref block (1, KVH, G,
+    hd), k/v_ref block (1, bs, KVH, hd), scratch acc (KVH, G, hd) and m/l
+    (KVH, G, 1)."""
+    for h in range(kvh):
+        q = q_ref[0, h]                              # (G, hd)
+        # MXU operands in the query's dtype (an int8 block converts exactly);
+        # float32 operands ask for float32 accuracy, which the MXU's default
+        # single bfloat16 pass does not give
+        prec = _HIGHEST if q.dtype == jnp.float32 else None
+        k = k_ref[0, :, h, :].astype(q.dtype)        # (bs, hd)
+        v = v_ref[0, :, h, :].astype(q.dtype)        # (bs, hd)
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+            precision=prec,
+        ) * scale                                    # (G, bs)
+        if ks_ref is not None:
+            # int8 pool: the block came over HBM->VMEM at one byte per
+            # element; dequantize with this (block, head)'s scale
+            s = s * ks_ref[0, :, h:h + 1]
+        s = jnp.where(valid, s, NEG_INF)
+        m_prev = m_ref[h]                            # (G, 1)
+        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_cur)
+        p = jnp.exp(s - m_cur)
+        l_ref[h] = l_ref[h] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        pv = jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=prec,
+        )                                            # (G, hd)
+        if vs_ref is not None:
+            pv = pv * vs_ref[0, :, h:h + 1]
+        acc_ref[h] = acc_ref[h] * alpha + pv
+        m_ref[h] = m_cur
+
+
+def _init_scratch(acc_ref, m_ref, l_ref):
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+
+def _finish(o_ref, acc_ref, l_ref):
+    l = jnp.maximum(l_ref[...], 1e-30)
+    o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+
+
+def _split_rest(rest, quantized):
+    if quantized:
+        return rest
+    return (None, None) + tuple(rest)
 
 
 def _paged_decode_kernel(tab_ref, len_ref, q_ref, k_ref, v_ref, *rest,
                          block_size: int, nkv: int, kvh: int, scale: float,
                          quantized: bool = False):
-    if quantized:
-        ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = rest
-    else:
-        o_ref, acc_ref, m_ref, l_ref = rest
+    ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = _split_rest(rest, quantized)
     b = pl.program_id(0)
     j = pl.program_id(1)
-    bb = b // kvh  # batch row (grid is B*KVH cells)
 
     @pl.when(j == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        _init_scratch(acc_ref, m_ref, l_ref)
 
-    q = q_ref[0].astype(jnp.float32)          # (G, hd)
-    k = k_ref[0, :, 0].astype(jnp.float32)    # (bs, hd)
-    v = v_ref[0, :, 0].astype(jnp.float32)    # (bs, hd)
-    if quantized:
-        # int8 pool: the block DMA'd HBM->VMEM half-width; dequantize in
-        # VMEM with this (block, kv-head)'s scalar scale — the bandwidth win
-        k = k * ks_ref[0, 0]
-        v = v * vs_ref[0, 0]
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale                                  # (G, bs)
     # logical position of this block's slots = j*bs + offset; valid when below
     # the sequence length AND backed by a real page — a raw -1 table entry is
     # masked here in the kernel (the index_map clamps it to block 0 only so
     # the DMA has a legal source), so callers may pass unclamped tables even
     # when interior entries are holes
-    kpos = j * block_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    backed = tab_ref[bb, j] >= 0
-    s = jnp.where(backed & (kpos < len_ref[bb]), s, NEG_INF)
-
-    m_prev = m_ref[...]
-    m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
-    alpha = jnp.exp(m_prev - m_cur)
-    p = jnp.exp(s - m_cur[:, None])
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1)
-    acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    m_ref[...] = m_cur
+    kpos = j * block_size + jax.lax.broadcasted_iota(jnp.int32, (1, block_size), 1)
+    valid = (tab_ref[b, j] >= 0) & (kpos < len_ref[b])
+    _online_softmax_heads(q_ref, k_ref, v_ref, ks_ref, vs_ref, acc_ref,
+                          m_ref, l_ref, valid, kvh=kvh, scale=scale)
 
     @pl.when(j == nkv - 1)
-    def _finish():
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+    def _done():
+        _finish(o_ref, acc_ref, l_ref)
+
+
+def _scratch(kvh, g, hd):
+    return [
+        pltpu.VMEM((kvh, g, hd), jnp.float32),
+        pltpu.VMEM((kvh, g, 1), jnp.float32),
+        pltpu.VMEM((kvh, g, 1), jnp.float32),
+    ]
+
+
+def _scale_specs(k_scale, v_scale, n_blocks, kvh, sc_map):
+    """(n_blocks, KVH) scales as (n_blocks, 1, KVH): a (1, 1, KVH) block
+    spans the array's last two dims, as Mosaic requires."""
+    spec = pl.BlockSpec((1, 1, kvh), sc_map)
+    return ([spec, spec],
+            [k_scale.reshape(n_blocks, 1, kvh), v_scale.reshape(n_blocks, 1, kvh)])
 
 
 def paged_decode_attention(
@@ -198,12 +253,12 @@ def paged_decode_attention(
     global pool; block_tables: (B, max_blocks) int32 (-1 = unallocated);
     lengths: (B,) valid tokens per sequence. Returns (B, H, hd).
 
-    Grid (B*KVH, max_blocks): the scalar-prefetched block table feeds the
-    K/V BlockSpec index_map, so each cell DMAs the one physical block its
-    logical block index maps to. The table may be RAW: -1 entries (pad or
-    interior holes) are masked to -inf inside the kernel, independent of the
-    length check. Lengths must be >= 1 per row (a fully-masked row would
-    softmax over nothing).
+    Grid (B, max_blocks): the scalar-prefetched block table feeds the K/V
+    BlockSpec index_map, so each cell DMAs the one physical block (all KV
+    heads) its logical block index maps to. The table may be RAW: -1
+    entries (pad or interior holes) are masked to -inf inside the kernel,
+    independent of the length check. Lengths must be >= 1 per row (a
+    fully-masked row would softmax over nothing).
 
     ``k_scale``/``v_scale`` ((n_blocks, KVH) float32, both or neither) mark
     an int8-quantized pool: each cell DMAs its block at half the HBM bytes
@@ -211,53 +266,50 @@ def paged_decode_attention(
     BlockSpec rides the same table-driven index_map as K/V.
     """
     B, H, hd = q.shape
-    bs, KVH = k_pool.shape[1], k_pool.shape[2]
+    nb, bs, KVH = k_pool.shape[0], k_pool.shape[1], k_pool.shape[2]
     G = H // KVH
     mb = block_tables.shape[1]
     scale = scale if scale is not None else 1.0 / math.sqrt(hd)
     quantized = k_scale is not None
 
-    qf = q.reshape(B, KVH, G, hd).reshape(B * KVH, G, hd)
+    qf = q.reshape(B, KVH, G, hd)
     tables = jnp.asarray(block_tables, jnp.int32)
     lens = jnp.asarray(lengths, jnp.int32).reshape(B)
 
     def q_map(b, j, tab_ref, len_ref):
-        return (b, 0, 0)
+        return (b, 0, 0, 0)
 
     def kv_map(b, j, tab_ref, len_ref):
-        return (jnp.maximum(tab_ref[b // KVH, j], 0), 0, b % KVH, 0)
+        return (jnp.maximum(tab_ref[b, j], 0), 0, 0, 0)
 
     def sc_map(b, j, tab_ref, len_ref):
-        return (jnp.maximum(tab_ref[b // KVH, j], 0), b % KVH)
+        return (jnp.maximum(tab_ref[b, j], 0), 0, 0)
 
     kernel = functools.partial(
         _paged_decode_kernel, block_size=bs, nkv=mb, kvh=KVH, scale=scale,
         quantized=quantized,
     )
     in_specs = [
-        pl.BlockSpec((1, G, hd), q_map),
-        pl.BlockSpec((1, bs, 1, hd), kv_map),
-        pl.BlockSpec((1, bs, 1, hd), kv_map),
+        pl.BlockSpec((1, KVH, G, hd), q_map),
+        pl.BlockSpec((1, bs, KVH, hd), kv_map),
+        pl.BlockSpec((1, bs, KVH, hd), kv_map),
     ]
     operands = [tables, lens, qf, k_pool, v_pool]
     if quantized:
-        in_specs += [pl.BlockSpec((1, 1), sc_map), pl.BlockSpec((1, 1), sc_map)]
-        operands += [k_scale, v_scale]
+        specs, scales = _scale_specs(k_scale, v_scale, nb, KVH, sc_map)
+        in_specs += specs
+        operands += scales
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B * KVH, mb),
+        grid=(B, mb),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, G, hd), q_map),
-        scratch_shapes=[
-            pltpu.VMEM((G, hd), jnp.float32),
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G,), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((1, KVH, G, hd), q_map),
+        scratch_shapes=_scratch(KVH, G, hd),
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B * KVH, G, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, KVH, G, hd), q.dtype),
         interpret=interpret,
     )(*operands)
     return out.reshape(B, KVH * G, hd)
@@ -301,57 +353,31 @@ def ref_paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
 def _paged_chunk_kernel(tab_ref, row_ref, slot_ref, pend_ref, sstart_ref,
                         q_ref, k_ref, v_ref, *rest, block_size: int, nkv: int,
                         kvh: int, scale: float, quantized: bool = False):
-    if quantized:
-        ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = rest
-    else:
-        o_ref, acc_ref, m_ref, l_ref = rest
-    c = pl.program_id(0)   # packed token x kv-head cell
+    ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = _split_rest(rest, quantized)
+    t = pl.program_id(0)   # packed token
     j = pl.program_id(1)   # logical kv block
-    t = c // kvh           # packed token index
 
     @pl.when(j == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        _init_scratch(acc_ref, m_ref, l_ref)
 
-    q = q_ref[0].astype(jnp.float32)          # (G, hd)
-    k = k_ref[0, :, 0].astype(jnp.float32)    # (bs, hd)
-    v = v_ref[0, :, 0].astype(jnp.float32)    # (bs, hd)
-    if quantized:
-        # dequantize the int8 block in VMEM (per-block, per-KV-head scale)
-        k = k * ks_ref[0, 0]
-        v = v * vs_ref[0, 0]
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale                                  # (G, bs)
     # the segmented-prompt span mask (models.transformer.apply_layer_prefix):
     # a token attends the shared prelude (slot < p_end) plus its own document
     # segment up to itself (s_start <= slot <= own slot); flat prompts and
     # decode rows pass p_end = s_start = 0, degenerating to plain causal.
     # Raw -1 table entries and packed pad tokens (row_of < 0) mask to -inf.
-    kpos = j * block_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    kpos = j * block_size + jax.lax.broadcasted_iota(jnp.int32, (1, block_size), 1)
     row = row_ref[t]
     backed = (row >= 0) & (tab_ref[jnp.maximum(row, 0), j] >= 0)
     span = (kpos < pend_ref[t]) | (
         (kpos >= sstart_ref[t]) & (kpos <= slot_ref[t])
     )
-    s = jnp.where(backed & span, s, NEG_INF)
-
-    m_prev = m_ref[...]
-    m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
-    alpha = jnp.exp(m_prev - m_cur)
-    p = jnp.exp(s - m_cur[:, None])
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1)
-    acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    m_ref[...] = m_cur
+    _online_softmax_heads(q_ref, k_ref, v_ref, ks_ref, vs_ref, acc_ref,
+                          m_ref, l_ref, backed & span, kvh=kvh, scale=scale)
 
     @pl.when(j == nkv - 1)
-    def _finish():
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+    def _done():
+        _finish(o_ref, acc_ref, l_ref)
 
 
 def paged_chunk_attention(
@@ -371,41 +397,42 @@ def paged_chunk_attention(
     segmented-prompt attention spans (zeros = plain causal over slots).
     Returns (T, H, hd).
 
-    Grid (T*KVH, max_blocks): one query token per cell row keeps the q tile
-    at (G, hd) — the decode kernel's shape — so the kernel is indifferent to
-    how rows were packed; ``block_tables[row_of[t]]`` drives the K/V
+    Grid (T, max_blocks): one query token per cell row keeps the q tile at
+    (KVH, G, hd) — the decode kernel's shape — so the kernel is indifferent
+    to how rows were packed; ``block_tables[row_of[t]]`` drives the K/V
     index_map through scalar prefetch. ``k_scale``/``v_scale`` ((n_blocks,
     KVH) float32) mark an int8 pool, dequantized in VMEM after the block DMA.
     """
     T, H, hd = q.shape
-    bs, KVH = k_pool.shape[1], k_pool.shape[2]
+    nb, bs, KVH = k_pool.shape[0], k_pool.shape[1], k_pool.shape[2]
     G = H // KVH
     mb = block_tables.shape[1]
     scale = scale if scale is not None else 1.0 / math.sqrt(hd)
     quantized = k_scale is not None
 
-    qf = q.reshape(T, KVH, G, hd).reshape(T * KVH, G, hd)
+    qf = q.reshape(T, KVH, G, hd)
     tables = jnp.asarray(block_tables, jnp.int32)
 
-    def q_map(c, j, tab_ref, row_ref, slot_ref, pend_ref, sstart_ref):
-        return (c, 0, 0)
+    def q_map(t, j, tab_ref, row_ref, slot_ref, pend_ref, sstart_ref):
+        return (t, 0, 0, 0)
 
-    def kv_map(c, j, tab_ref, row_ref, slot_ref, pend_ref, sstart_ref):
-        row = jnp.maximum(row_ref[c // KVH], 0)
-        return (jnp.maximum(tab_ref[row, j], 0), 0, c % KVH, 0)
+    def block_of(t, j, tab_ref, row_ref):
+        return jnp.maximum(tab_ref[jnp.maximum(row_ref[t], 0), j], 0)
 
-    def sc_map(c, j, tab_ref, row_ref, slot_ref, pend_ref, sstart_ref):
-        row = jnp.maximum(row_ref[c // KVH], 0)
-        return (jnp.maximum(tab_ref[row, j], 0), c % KVH)
+    def kv_map(t, j, tab_ref, row_ref, slot_ref, pend_ref, sstart_ref):
+        return (block_of(t, j, tab_ref, row_ref), 0, 0, 0)
+
+    def sc_map(t, j, tab_ref, row_ref, slot_ref, pend_ref, sstart_ref):
+        return (block_of(t, j, tab_ref, row_ref), 0, 0)
 
     kernel = functools.partial(
         _paged_chunk_kernel, block_size=bs, nkv=mb, kvh=KVH, scale=scale,
         quantized=quantized,
     )
     in_specs = [
-        pl.BlockSpec((1, G, hd), q_map),
-        pl.BlockSpec((1, bs, 1, hd), kv_map),
-        pl.BlockSpec((1, bs, 1, hd), kv_map),
+        pl.BlockSpec((1, KVH, G, hd), q_map),
+        pl.BlockSpec((1, bs, KVH, hd), kv_map),
+        pl.BlockSpec((1, bs, KVH, hd), kv_map),
     ]
     operands = [
         tables, jnp.asarray(row_of, jnp.int32), jnp.asarray(slots, jnp.int32),
@@ -413,23 +440,20 @@ def paged_chunk_attention(
         qf, k_pool, v_pool,
     ]
     if quantized:
-        in_specs += [pl.BlockSpec((1, 1), sc_map), pl.BlockSpec((1, 1), sc_map)]
-        operands += [k_scale, v_scale]
+        specs, scales = _scale_specs(k_scale, v_scale, nb, KVH, sc_map)
+        in_specs += specs
+        operands += scales
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
-        grid=(T * KVH, mb),
+        grid=(T, mb),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, G, hd), q_map),
-        scratch_shapes=[
-            pltpu.VMEM((G, hd), jnp.float32),
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G,), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((1, KVH, G, hd), q_map),
+        scratch_shapes=_scratch(KVH, G, hd),
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((T * KVH, G, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((T, KVH, G, hd), q.dtype),
         interpret=interpret,
     )(*operands)
     return out.reshape(T, KVH * G, hd)

@@ -13,29 +13,17 @@ from typing import Dict, Sequence, Tuple
 import jax
 
 
-def make_mesh_compat(shape: Sequence[int], axes: Tuple[str, ...]):
-    """Version-portable ``jax.make_mesh``.
-
-    Newer JAX exposes ``jax.sharding.AxisType`` and ``make_mesh`` accepts an
-    ``axis_types`` kwarg; JAX 0.4.x has neither. Pass it when available, fall
-    back to the plain call (equivalent: Auto is the default axis semantics).
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(
-                tuple(shape), tuple(axes),
-                axis_types=(axis_type.Auto,) * len(axes),
-            )
-        except TypeError:  # AxisType exists but make_mesh predates the kwarg
-            pass
-    return jax.make_mesh(tuple(shape), tuple(axes))
+def make_mesh(shape: Sequence[int], axes: Tuple[str, ...]):
+    """``jax.make_mesh`` with Auto axes (JAX's default is Explicit): the
+    partitioner places whatever the shardings leave open."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_serving_mesh(tp: int = 1, dp: int = 1):
@@ -49,13 +37,13 @@ def make_serving_mesh(tp: int = 1, dp: int = 1):
             f"serving mesh tp={tp} dp={dp} needs {tp * dp} devices, have {n}"
         )
     if dp > 1:
-        return make_mesh_compat((dp, tp), ("data", "model"))
-    return make_mesh_compat((tp,), ("model",))
+        return make_mesh((dp, tp), ("data", "model"))
+    return make_mesh((tp,), ("model",))
 
 
 def mesh_axis_sizes(mesh) -> Dict[str, int]:
     """Axis name -> size for any mesh built here; round-trips through
-    ``make_mesh_compat`` (mesh_axis_sizes(make_mesh_compat(shape, axes)) ==
+    ``make_mesh`` (mesh_axis_sizes(make_mesh(shape, axes)) ==
     dict(zip(axes, shape)))."""
     return dict(zip(mesh.axis_names, mesh.devices.shape))
 

@@ -268,7 +268,7 @@ class GenerationEngine:
         host_bw_bytes_s: float = 8e9,
         copy_budget: int = 4,
         telemetry: Any = None,
-        kernel: str = "reference",
+        kernel: Optional[str] = None,
         ragged: bool = True,
         pack_align: int = 4,
         kv_dtype: Optional[str] = None,
@@ -301,10 +301,12 @@ class GenerationEngine:
         ``pipeline=False`` is the eager sync oracle, greedy-token-identical.
 
         ``kernel`` selects the paged hot-path attention implementation:
-        ``"reference"`` (default) is the jnp gather oracle; ``"pallas"``
-        runs ``kernels.paged_decode_attention`` for decode plans and
-        ``kernels.paged_chunk_attention`` for the ragged fused step —
-        compiled Mosaic on TPU, interpret mode elsewhere. ``ragged``
+        ``"pallas"`` runs ``kernels.paged_decode_attention`` for decode
+        plans and ``kernels.paged_chunk_attention`` for the ragged fused
+        step; ``"reference"`` is the jnp gather oracle. ``None`` lets the
+        platform pick: compiled Pallas on a TPU, the reference elsewhere
+        (where Pallas would only run in its interpreter). A mesh needs an
+        explicit ``"reference"``. ``ragged``
         (interleaved mode) packs the fused mixed batch into one flat token
         buffer (decode rows cost one slot, not a chunk-width row; tables go
         to the device RAW, unbacked pages masked in the kernel);
@@ -371,12 +373,15 @@ class GenerationEngine:
         if preempt not in ("recompute", "swap", "cost"):
             raise ValueError(f"unknown preempt strategy {preempt!r}")
         self.preempt = preempt
+        if kernel is None:
+            kernel = "reference" if default_interpret() else "pallas"
         if kernel not in ("reference", "pallas"):
             raise ValueError(f"unknown kernel {kernel!r}")
         if kernel == "pallas" and (pool_layout is not None or mesh is not None):
             raise ValueError(
                 "kernel='pallas' is single-device only: the Pallas paged "
-                "kernels do not partition under shard_map meshes yet"
+                "kernels do not partition under shard_map meshes yet; pass "
+                "kernel='reference' on a mesh"
             )
         if kernel == "pallas" and not ragged:
             raise ValueError(
@@ -1644,7 +1649,7 @@ class DataParallelEngineGroup:
                  seed: int = 0, host_store: Optional[HostBlockStore] = None,
                  host_blocks: Optional[int] = None,
                  kv_dtype: Optional[str] = None, sanitize: bool = False,
-                 **engine_kwargs):
+                 params=None, **engine_kwargs):
         if dp < 1:
             raise ValueError("dp must be >= 1")
         max_blocks = -(-max_seq // block_size)
@@ -1667,7 +1672,6 @@ class DataParallelEngineGroup:
         engine_kwargs.setdefault("flusher", self.flusher)
         self.engines: List[GenerationEngine] = []
         arrays: Optional[PoolArrays] = None
-        params = None
         # one sanitizer spans the whole group: replicas allocate from
         # disjoint ranges of one shared pool array, so a shared shadow also
         # catches cross-replica double-ownership of a block

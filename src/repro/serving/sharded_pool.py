@@ -154,7 +154,7 @@ def make_pool_layout(
 
     Returns None for the degenerate no-mesh/tp=1/dp=1 case so callers keep
     the legacy unsharded path (bit-identical, no placement machinery)."""
-    from repro.launch.mesh import make_mesh_compat
+    from repro.launch.mesh import make_mesh
 
     if mesh is not None:
         return ShardedPoolLayout(mesh, dp_blocks=dp_blocks)
@@ -162,7 +162,7 @@ def make_pool_layout(
     if tp <= 1 and dp <= 1:
         return None
     if dp > 1:
-        mesh = make_mesh_compat((dp, tp), ("data", "model"))
+        mesh = make_mesh((dp, tp), ("data", "model"))
     else:
-        mesh = make_mesh_compat((tp,), ("model",))
+        mesh = make_mesh((tp,), ("model",))
     return ShardedPoolLayout(mesh, dp_blocks=dp_blocks)

@@ -108,7 +108,8 @@ def _assert_decode_matches(case, interpret):
     q, kp, vp, tables, lengths = case
     got = paged_decode_attention(q, kp, vp, tables, lengths,
                                  interpret=interpret)
-    want = ref_paged_decode_attention(q, kp, vp, tables, lengths)
+    with jax.default_matmul_precision("highest"):  # a float32 oracle on TPU too
+        want = ref_paged_decode_attention(q, kp, vp, tables, lengths)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), **TOL)
 
 
@@ -116,8 +117,9 @@ def _assert_chunk_matches(case, interpret):
     q, kp, vp, tables, row_of, slots, p_end, s_start = case
     got = paged_chunk_attention(q, kp, vp, tables, row_of, slots, p_end,
                                 s_start, interpret=interpret)
-    want = ref_paged_chunk_attention(q, kp, vp, tables, row_of, slots, p_end,
-                                     s_start)
+    with jax.default_matmul_precision("highest"):
+        want = ref_paged_chunk_attention(q, kp, vp, tables, row_of, slots,
+                                         p_end, s_start)
     valid = np.asarray(row_of) >= 0
     got, want = np.asarray(got), np.asarray(want)
     assert np.all(np.isfinite(got)), "pad rows must be garbage-but-FINITE"

@@ -15,7 +15,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_arch, smoke_variant
-from repro.launch.mesh import make_mesh_compat, make_serving_mesh, mesh_axis_sizes
+from repro.launch.mesh import make_mesh, make_serving_mesh, mesh_axis_sizes
 from repro.serving.engine import DataParallelEngineGroup, GenerationEngine
 from repro.serving.paged_cache import PagedKVCache, PagedPool
 from repro.serving.segments import assemble_prompt
@@ -79,11 +79,11 @@ def test_single_device_audits_collective_free():
 
 
 def test_mesh_axis_sizes_roundtrip():
-    """mesh_axis_sizes inverts make_mesh_compat for every shape/axes pair the
+    """mesh_axis_sizes inverts make_mesh for every shape/axes pair the
     serving layer builds (single-device shapes here; multi-device in the
     subprocess test)."""
     for shape, axes in [((1,), ("model",)), ((1, 1), ("data", "model"))]:
-        mesh = make_mesh_compat(shape, axes)
+        mesh = make_mesh(shape, axes)
         assert mesh_axis_sizes(mesh) == dict(zip(axes, shape))
     assert mesh_axis_sizes(make_serving_mesh(tp=1)) == {"model": 1}
     assert mesh_axis_sizes(make_serving_mesh(tp=1, dp=1)) == {"model": 1}
@@ -304,7 +304,7 @@ from dataclasses import replace
 import numpy as np
 import pytest
 from repro.configs import get_arch, smoke_variant
-from repro.launch.mesh import make_mesh_compat, make_serving_mesh, mesh_axis_sizes
+from repro.launch.mesh import make_mesh, make_serving_mesh, mesh_axis_sizes
 from repro.serving.engine import GenerationEngine
 from repro.serving.segments import assemble_prompt
 from repro.serving.sharded_pool import ShardedPoolLayout
@@ -327,7 +327,7 @@ def prompts():
     return out
 
 # multi-device mesh round-trips
-assert mesh_axis_sizes(make_mesh_compat((2, 4), ("data", "model"))) == {"data": 2, "model": 4}
+assert mesh_axis_sizes(make_mesh((2, 4), ("data", "model"))) == {"data": 2, "model": 4}
 assert mesh_axis_sizes(make_serving_mesh(tp=4, dp=2)) == {"data": 2, "model": 4}
 
 # explicit layout validation: indivisible heads are rejected, not degraded

@@ -12,13 +12,13 @@ import sys
 sys.path.insert(0, "src")
 import jax, jax.numpy as jnp
 import numpy as np
-from repro.launch.mesh import make_mesh_compat
+from repro.launch.mesh import make_mesh
 from repro.models.shardmap_tp import (
     count_collectives, make_tp_block, shard_tp_weights, tp_block_pjit,
     tp_block_reference,
 )
 
-mesh = make_mesh_compat((8,), ("model",))
+mesh = make_mesh((8,), ("model",))
 ks = jax.random.split(jax.random.PRNGKey(0), 3)
 B, D, F = 4, 64, 256
 x = jax.random.normal(ks[0], (B, D))
