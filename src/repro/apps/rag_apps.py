@@ -360,7 +360,9 @@ class EnginePipeline:
     deadline slack; each stage completion is observed back into the model
     (data-dependent paths re-estimate as they unfold). A ``Session`` threads
     multi-turn history into the answer stage's prompt and is committed with
-    the decoded answer when the path drains.
+    the decoded answer when the path drains. Every stage's request carries
+    the first stage's request id as its trace id, so the engine's
+    ``telemetry.critical_path(trace_id)`` lists the pipeline's stages.
     """
 
     #: shared retrieval universe (small so cross-request doc reuse is real)
@@ -397,6 +399,7 @@ class EnginePipeline:
         self.answer = np.zeros(0, np.int32)
         self.requests: List[object] = []
         self._inflight = None      # (request, name, t_submit, features)
+        self.trace_id: Optional[int] = None
         self._seen: Dict[str, int] = {}
         self.done = False
         self.started_at: Optional[float] = None
@@ -475,7 +478,9 @@ class EnginePipeline:
                 req = self.engine.submit(
                     self._build_prompt(comp, is_answer),
                     max_new=_stage_max_new(comp, self.max_new),
-                    temperature=0.0, priority=prio)
+                    temperature=0.0, priority=prio, trace_id=self.trace_id)
+                if self.trace_id is None:
+                    self.trace_id = req.trace_id
                 self._inflight = (req, name, now, feats)
                 return False
             # CPU stages resolve synchronously on the driver thread

@@ -7,13 +7,23 @@ frequencies, critical paths). This module provides:
   * Dapper-style trace spans per request stage (queue + service + transfer),
   * time-series gauges (queue depth, instance count, chunk size, pool
     utilization) sampled on events,
-  * critical-path extraction over a request's spans.
+  * critical-path extraction over a request's spans,
+  * timed code spans (``Telemetry.span``): a ``jax.profiler`` annotation
+    named ``pw:<name>`` on the profiler's clock, beside the device ops of
+    the same trace, plus an always-on count and total in integer
+    nanoseconds per name. Nesting on a thread gives each span its parent in
+    the trace; the dotted name gives it in the aggregate
+    (``engine.plan.admit`` inside ``engine.plan``). Nothing is kept per
+    call.
 """
 from __future__ import annotations
 
 from collections import defaultdict
-from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Tuple
+from dataclasses import dataclass
+from time import perf_counter_ns
+from typing import Dict, List, Tuple
+
+SPAN_PREFIX = "pw:"
 
 
 @dataclass
@@ -34,15 +44,75 @@ class Span:
         return self.finished - self.started
 
 
+class _Timed:
+    """One timed span: the profiler annotation (made only while a profile
+    is being recorded) and the aggregate's update."""
+
+    __slots__ = ("_label", "_agg", "_annotation", "_ann", "_t0")
+
+    def __init__(self, label: str, agg: List[int], annotation):
+        self._label = label
+        self._agg = agg
+        self._annotation = annotation   # jax.profiler.TraceAnnotation
+
+    def __enter__(self):
+        if self._annotation.is_enabled():
+            self._ann = self._annotation(self._label)
+            self._ann.__enter__()
+        else:
+            self._ann = None
+        self._t0 = perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc):
+        dt = perf_counter_ns() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        agg = self._agg
+        agg[0] += 1
+        agg[1] += dt
+        return False
+
+
 class Telemetry:
+    """``max_series`` bounds each gauge's samples and the number of request
+    spans kept (the oldest traces go first)."""
+
     def __init__(self, max_series: int = 100_000):
         self.spans: Dict[int, List[Span]] = defaultdict(list)
         self.gauges: Dict[str, List[Tuple[float, float]]] = defaultdict(list)
         self._max = max_series
+        self._n_spans = 0
+        # span name -> its profiler label, [count, total ns], and the
+        # annotation class
+        self._timed: Dict[str, Tuple[str, List[int], type]] = {}
 
     # ------------------------------------------------------------ recording
     def record_span(self, span: Span):
         self.spans[span.req_id].append(span)
+        self._n_spans += 1
+        while self._n_spans > self._max:
+            oldest = next(iter(self.spans))
+            self._n_spans -= len(self.spans.pop(oldest))
+
+    def span(self, name: str) -> _Timed:
+        """Context manager timing the enclosed code as ``name``."""
+        entry = self._timed.get(name)
+        if entry is None:
+            from jax.profiler import TraceAnnotation
+
+            entry = self._timed[name] = (SPAN_PREFIX + name, [0, 0],
+                                         TraceAnnotation)
+        return _Timed(*entry)
+
+    def span_totals(self) -> Dict[str, int]:
+        """Each timed span's count and total as flat integers:
+        ``<name>_n`` and ``<name>_ns``."""
+        out: Dict[str, int] = {}
+        for name, (_label, (n, ns), _cls) in self._timed.items():
+            out[f"{name}_n"] = n
+            out[f"{name}_ns"] = ns
+        return out
 
     def gauge(self, name: str, t: float, value: float):
         series = self.gauges[name]

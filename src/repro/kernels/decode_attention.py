@@ -126,6 +126,7 @@ def decode_attention(
             pltpu.VMEM((G,), jnp.float32),
         ],
         interpret=interpret,
+        name="decode_attention",
     )(lens_rep, qf, kf, vf)
     return out.reshape(B, KVH * G, hd)
 
@@ -311,6 +312,7 @@ def paged_decode_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KVH, G, hd), q.dtype),
         interpret=interpret,
+        name="paged_decode_attention",
     )(*operands)
     return out.reshape(B, KVH * G, hd)
 
@@ -455,6 +457,7 @@ def paged_chunk_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T, KVH, G, hd), q.dtype),
         interpret=interpret,
+        name="paged_chunk_attention",
     )(*operands)
     return out.reshape(T, KVH * G, hd)
 

@@ -261,18 +261,23 @@ def prefill_packed(cfg, params, k_pool, v_pool, tables, tokens, row_of, slots,
     writes requantize through ``write_paged_packed_q`` and both attention
     impls dequantize at read. Requires ``paged_cache_supported``
     (full-attention GQA, rope, period 1)."""
-    x = embed_tokens(params["embed"], tokens[None])          # (1, T, D)
-    x, k_pool, v_pool, k_scales, v_scales = tfm.run_stack_paged(
-        cfg, params["blocks"], x, k_pool, v_pool, tables, row_of, slots,
-        positions, p_end, s_start, block_size=block_size,
-        null_block=null_block, impl=impl, interpret=interpret,
-        k_scales=k_scales, v_scales=v_scales,
-    )
-    x = tfm.apply_norm(cfg, params["final_norm"], x)
-    logits = unembed(params["embed"], params.get("lm_head"), x, cfg.tie_embeddings)
-    if cfg.padded_vocab != cfg.vocab_size:  # mask pad-vocab logits (as forward)
-        pad_bias = jnp.where(jnp.arange(cfg.padded_vocab) < cfg.vocab_size, 0.0, -1e30)
-        logits = logits + pad_bias.astype(logits.dtype)
+    with jax.named_scope("embed"):
+        x = embed_tokens(params["embed"], tokens[None])      # (1, T, D)
+    with jax.named_scope("layers"):
+        x, k_pool, v_pool, k_scales, v_scales = tfm.run_stack_paged(
+            cfg, params["blocks"], x, k_pool, v_pool, tables, row_of, slots,
+            positions, p_end, s_start, block_size=block_size,
+            null_block=null_block, impl=impl, interpret=interpret,
+            k_scales=k_scales, v_scales=v_scales,
+        )
+    with jax.named_scope("head"):
+        x = tfm.apply_norm(cfg, params["final_norm"], x)
+        logits = unembed(params["embed"], params.get("lm_head"), x,
+                         cfg.tie_embeddings)
+        if cfg.padded_vocab != cfg.vocab_size:  # mask pad-vocab logits (as forward)
+            pad_bias = jnp.where(jnp.arange(cfg.padded_vocab) < cfg.vocab_size,
+                                 0.0, -1e30)
+            logits = logits + pad_bias.astype(logits.dtype)
     return logits[0], k_pool, v_pool, k_scales, v_scales
 
 
@@ -285,14 +290,18 @@ def decode_step_paged(cfg, params, k_pool, v_pool, tables, tokens, pos, *,
     v_pool, k_scales, v_scales); scales are None unless the pool is
     int8-quantized, in which case the kernel dequantizes per-block in VMEM.
     Requires ``paged_cache_supported``."""
-    x = embed_tokens(params["embed"], tokens)
-    x, k_pool, v_pool, k_scales, v_scales = tfm.run_stack_decode_paged(
-        cfg, params["blocks"], x, k_pool, v_pool, tables, pos,
-        block_size=block_size, null_block=null_block, interpret=interpret,
-        k_scales=k_scales, v_scales=v_scales,
-    )
-    x = tfm.apply_norm(cfg, params["final_norm"], x)
-    logits = unembed(params["embed"], params.get("lm_head"), x, cfg.tie_embeddings)
+    with jax.named_scope("embed"):
+        x = embed_tokens(params["embed"], tokens)
+    with jax.named_scope("layers"):
+        x, k_pool, v_pool, k_scales, v_scales = tfm.run_stack_decode_paged(
+            cfg, params["blocks"], x, k_pool, v_pool, tables, pos,
+            block_size=block_size, null_block=null_block, interpret=interpret,
+            k_scales=k_scales, v_scales=v_scales,
+        )
+    with jax.named_scope("head"):
+        x = tfm.apply_norm(cfg, params["final_norm"], x)
+        logits = unembed(params["embed"], params.get("lm_head"), x,
+                         cfg.tie_embeddings)
     return logits[:, 0], k_pool, v_pool, k_scales, v_scales
 
 

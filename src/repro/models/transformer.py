@@ -586,47 +586,57 @@ def apply_layer_paged(cfg, kind, lp, x, k_slice, v_slice, tables, row_of,
         raise NotImplementedError(
             "ragged paged prefill supports full-attention GQA stacks only"
         )
-    xn = apply_norm(cfg, lp["norm1"], x)
-    q, k, v = attn.qkv_project(
-        lp["attn"], xn, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    )
-    if cfg.use_rope:
-        q = apply_rope(q, positions[None], cfg.rope_theta)
-        k = apply_rope(k, positions[None], cfg.rope_theta)
-    if k_sc is None:
-        k_slice = write_paged_packed(
-            k_slice, tables, row_of, slots, k[0], block_size, null_block
+    with jax.named_scope("qkv"):
+        xn = apply_norm(cfg, lp["norm1"], x)
+        q, k, v = attn.qkv_project(
+            lp["attn"], xn, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
         )
-        v_slice = write_paged_packed(
-            v_slice, tables, row_of, slots, v[0], block_size, null_block
-        )
-    else:
-        k_slice, k_sc = write_paged_packed_q(
-            k_slice, k_sc, tables, row_of, slots, k[0], block_size, null_block
-        )
-        v_slice, v_sc = write_paged_packed_q(
-            v_slice, v_sc, tables, row_of, slots, v[0], block_size, null_block
-        )
-    if impl == "pallas":
-        a_out = paged_chunk_attention(
-            q[0], k_slice, v_slice, tables, row_of, slots, p_end, s_start,
-            k_scale=k_sc, v_scale=v_sc, interpret=interpret,
-        )
-    else:
-        a_out = ref_paged_chunk_attention(
-            q[0], k_slice, v_slice, tables, row_of, slots, p_end, s_start,
-            k_scale=k_sc, v_scale=v_sc,
-        )
-    T = x.shape[1]
-    x = x + (a_out.reshape(1, T, cfg.num_heads * cfg.head_dim)
-             @ lp["attn"]["wo"])
+        if cfg.use_rope:
+            q = apply_rope(q, positions[None], cfg.rope_theta)
+            k = apply_rope(k, positions[None], cfg.rope_theta)
+    with jax.named_scope("pool_write"):
+        if k_sc is None:
+            k_slice = write_paged_packed(
+                k_slice, tables, row_of, slots, k[0], block_size, null_block
+            )
+            v_slice = write_paged_packed(
+                v_slice, tables, row_of, slots, v[0], block_size, null_block
+            )
+        else:
+            k_slice, k_sc = write_paged_packed_q(
+                k_slice, k_sc, tables, row_of, slots, k[0], block_size,
+                null_block
+            )
+            v_slice, v_sc = write_paged_packed_q(
+                v_slice, v_sc, tables, row_of, slots, v[0], block_size,
+                null_block
+            )
+    with jax.named_scope("attn"):
+        if impl == "pallas":
+            a_out = paged_chunk_attention(
+                q[0], k_slice, v_slice, tables, row_of, slots, p_end, s_start,
+                k_scale=k_sc, v_scale=v_sc, interpret=interpret,
+            )
+        else:
+            a_out = ref_paged_chunk_attention(
+                q[0], k_slice, v_slice, tables, row_of, slots, p_end, s_start,
+                k_scale=k_sc, v_scale=v_sc,
+            )
+        T = x.shape[1]
+        x = x + (a_out.reshape(1, T, cfg.num_heads * cfg.head_dim)
+                 @ lp["attn"]["wo"])
+    return _mlp_residual(cfg, lp, x), k_slice, v_slice, k_sc, v_sc
 
-    xn = apply_norm(cfg, lp["norm2"], x)
-    if "moe" in lp:
-        ffn_out, _ = moe_mod.apply_moe(lp["moe"], xn, cfg)
-    else:
-        ffn_out = apply_mlp(lp["mlp"], xn, cfg.act)
-    return x + ffn_out, k_slice, v_slice, k_sc, v_sc
+
+def _mlp_residual(cfg, lp, x):
+    """The paged layers' second half: norm, MLP (or MoE), residual."""
+    with jax.named_scope("mlp"):
+        xn = apply_norm(cfg, lp["norm2"], x)
+        if "moe" in lp:
+            ffn_out, _ = moe_mod.apply_moe(lp["moe"], xn, cfg)
+        else:
+            ffn_out = apply_mlp(lp["mlp"], xn, cfg.act)
+        return x + ffn_out
 
 
 def run_stack_paged(cfg, blocks, x, k_pool, v_pool, tables, row_of, slots,
@@ -683,14 +693,15 @@ def apply_layer_decode_paged(cfg, kind, lp, x, k_slice, v_slice, tables, pos,
         )
     B = x.shape[0]
     bs = block_size
-    xn = apply_norm(cfg, lp["norm1"], x)
-    q, k, v = attn.qkv_project(
-        lp["attn"], xn, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    )
-    if cfg.use_rope:
-        positions = pos[:, None].astype(jnp.int32)
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
+    with jax.named_scope("qkv"):
+        xn = apply_norm(cfg, lp["norm1"], x)
+        q, k, v = attn.qkv_project(
+            lp["attn"], xn, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+        )
+        if cfg.use_rope:
+            positions = pos[:, None].astype(jnp.int32)
+            q = apply_rope(q, positions, cfg.rope_theta)
+            k = apply_rope(k, positions, cfg.rope_theta)
     blk = tables[jnp.arange(B), pos // bs]
     dest = jnp.where(blk >= 0, blk * bs + pos % bs, null_block * bs)
 
@@ -703,25 +714,21 @@ def apply_layer_decode_paged(cfg, kind, lp, x, k_slice, v_slice, tables, pos,
         p, s = _quantized_scatter(pool[None], sc[None], dest, new[None])
         return p[0], s[0]
 
-    if k_sc is None:
-        k_slice = scatter(k_slice, k[:, 0])
-        v_slice = scatter(v_slice, v[:, 0])
-    else:
-        k_slice, k_sc = scatter_q(k_slice, k_sc, k[:, 0])
-        v_slice, v_sc = scatter_q(v_slice, v_sc, v[:, 0])
-    a_out = paged_decode_attention(
-        q[:, 0], k_slice, v_slice, tables, pos + 1,
-        k_scale=k_sc, v_scale=v_sc, interpret=interpret
-    )
-    x = x + (a_out.reshape(B, 1, cfg.num_heads * cfg.head_dim)
-             @ lp["attn"]["wo"])
-
-    xn = apply_norm(cfg, lp["norm2"], x)
-    if "moe" in lp:
-        ffn_out, _ = moe_mod.apply_moe(lp["moe"], xn, cfg)
-    else:
-        ffn_out = apply_mlp(lp["mlp"], xn, cfg.act)
-    return x + ffn_out, k_slice, v_slice, k_sc, v_sc
+    with jax.named_scope("pool_write"):
+        if k_sc is None:
+            k_slice = scatter(k_slice, k[:, 0])
+            v_slice = scatter(v_slice, v[:, 0])
+        else:
+            k_slice, k_sc = scatter_q(k_slice, k_sc, k[:, 0])
+            v_slice, v_sc = scatter_q(v_slice, v_sc, v[:, 0])
+    with jax.named_scope("attn"):
+        a_out = paged_decode_attention(
+            q[:, 0], k_slice, v_slice, tables, pos + 1,
+            k_scale=k_sc, v_scale=v_sc, interpret=interpret
+        )
+        x = x + (a_out.reshape(B, 1, cfg.num_heads * cfg.head_dim)
+                 @ lp["attn"]["wo"])
+    return _mlp_residual(cfg, lp, x), k_slice, v_slice, k_sc, v_sc
 
 
 def run_stack_decode_paged(cfg, blocks, x, k_pool, v_pool, tables, pos, *,

@@ -161,14 +161,17 @@ class ControlPlane:
             i = eng.scheduler.select(eng.waiting)
             req = eng.waiting[i]
             if not req.swapped and eng._prefix_pending(req):
+                eng.admit_deferred += 1
                 break  # leader still prefilling this prefix; wait to share it
             was_swapped = req.swapped  # _try_admit clears it on restore
             if not eng._try_admit(req):
                 if req.done:  # unfittable request failed out; try the next
                     eng.waiting.pop(i)
                     continue
+                eng.admit_blocked += 1
                 break  # the policy's head-of-line waits for blocks
             eng.waiting.pop(i)
+            eng._mark_admitted(req)
             slot = free.pop(0)
             if not was_swapped:
                 cap = eng._prompt_cap(req)
@@ -200,36 +203,41 @@ class ControlPlane:
         """One step's decisions, host-side only. Returns None when there is
         nothing to run (no active slots after admission)."""
         eng = self.eng
-        self.admit()
-        eng._ensure_decode_capacity()
-        active = [r for r in eng.slots if r is not None]
-        self._apply_chunk_policy(active)
-        if not active:
-            return None
-        plan_id = self._next_plan_id
-        self._next_plan_id += 1
-        self.plans_built += 1
+        span = eng.telemetry.span
+        with span("engine.plan"):
+            with span("engine.plan.admit"):
+                self.admit()
+            eng._ensure_decode_capacity()
+            active = [r for r in eng.slots if r is not None]
+            self._apply_chunk_policy(active)
+            if not active:
+                return None
+            plan_id = self._next_plan_id
+            self._next_plan_id += 1
+            self.plans_built += 1
 
-        prefill_rows = sorted((r for r in active if r.prefilling),
-                              key=lambda r: r.req_id)
-        decode_rows = [r for r in active if not r.prefilling]
-        B = eng.max_batch
-        prev_slots = np.full((B,), -1, np.int32)
+            prefill_rows = sorted((r for r in active if r.prefilling),
+                                  key=lambda r: r.req_id)
+            decode_rows = [r for r in active if not r.prefilling]
+            B = eng.max_batch
+            prev_slots = np.full((B,), -1, np.int32)
 
-        if prefill_rows:
-            assemble = (self._assemble_ragged if eng.ragged
-                        else self._assemble_fused)
-            plan = assemble(plan_id, active, prefill_rows, decode_rows,
-                            prev_slots)
-        else:
-            plan = self._assemble_decode(plan_id, active, prev_slots)
+            with span("engine.plan.assemble"):
+                if prefill_rows:
+                    assemble = (self._assemble_ragged if eng.ragged
+                                else self._assemble_fused)
+                    plan = assemble(plan_id, active, prefill_rows,
+                                    decode_rows, prev_slots)
+                else:
+                    plan = self._assemble_decode(plan_id, active, prev_slots)
 
-        # build-time completion: finishing rows release slot + blocks NOW so
-        # the next plan can admit into them; emission happens at materialize
-        for req, _row, finishing in plan.emit_rows:
-            if finishing:
-                eng._retire_slot(req)
-        return plan
+            # build-time completion: finishing rows release slot + blocks
+            # NOW so the next plan can admit into them; emission happens at
+            # materialize
+            for req, _row, finishing in plan.emit_rows:
+                if finishing:
+                    eng._retire_slot(req)
+            return plan
 
     def _grants(self, prefill_rows, decode_rows) -> Dict[int, int]:
         """Token-budget grants: decode rows reserve one token each; the

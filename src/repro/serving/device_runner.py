@@ -17,13 +17,16 @@ The runner is the device half of the control-plane split. Its contract:
 
 * **Host-gap accounting.** The wall time the device sat idle between the
   completion of one step and the dispatch of the next is the quantity the
-  whole refactor exists to shrink; the runner measures it (ready-probe at
-  build start + blocking materializes) instead of asserting it.
+  whole refactor exists to shrink; the runner estimates it (ready-probe at
+  build start + blocking materializes). The probe misses idle time it does
+  not observe; a profiler trace, with the runner's ``engine.dispatch.*``
+  and ``engine.materialize.wait`` spans beside the device ops, is the
+  authority.
 """
 from __future__ import annotations
 
 import time
-from typing import Any, List, Optional
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -62,6 +65,16 @@ def _is_ready(arr) -> bool:
         return True
 
 
+# the plan arrays each kind of step uploads
+_INPUTS = {
+    "ragged": ("tokens", "prev_slots", "decode_idx", "tables", "row_of", "slots",
+               "positions", "p_end", "s_start", "last_idx", "temps"),
+    "fused": ("tokens", "prev_slots", "tables", "starts", "n_valid", "positions",
+              "p_end", "s_start", "temps"),
+    "decode": ("tokens", "prev_slots", "tables", "starts", "temps"),
+}
+
+
 class PlanExec:
     """A dispatched plan: the device future of its sampled tokens."""
 
@@ -83,7 +96,7 @@ class DeviceRunner:
         self._outstanding: Optional[PlanExec] = None  # newest unmaterialized
         self._idle_mark: Optional[float] = None       # when idleness observed
         self.host_gap_s = 0.0
-        self.gap_samples: List[float] = []
+        self.n_gaps = 0          # dispatches that followed an earlier one
         self.n_dispatched = 0
         # online per-valid-token step time (EMA over materialized plans);
         # the cost-model preemption's recompute estimate consumes it
@@ -103,65 +116,62 @@ class DeviceRunner:
     # ------------------------------------------------------------- dispatch
     def dispatch(self, plan: StepPlan) -> PlanExec:
         eng = self.eng
-        now = time.perf_counter()
+        span = eng.telemetry.span
+        with span("engine.dispatch"):
+            now = time.perf_counter()
+            self._account_gap(now)
+            with span("engine.dispatch.inputs"):
+                # the plan's host arrays become device arrays, by kind
+                dev = {f: jnp.asarray(getattr(plan, f))
+                       for f in _INPUTS[plan.kind]}
+            with span("engine.dispatch.launch"):
+                eng._key, sk = jax.random.split(eng._key)
+                toks = self._launch(plan.kind, dev, sk)
+            ex = PlanExec(plan, toks, now)
+            self._last = ex
+            self._outstanding = ex
+            self.last_plan_id = plan.plan_id
+            self.n_dispatched += 1
+            return ex
+
+    def _account_gap(self, now: float) -> None:
         if self._outstanding is not None and self._idle_mark is None:
             # late probe: the step may have finished mid-build; counting the
             # gap from now underestimates, never inflates, the idle time
             if _is_ready(self._outstanding.tokens):
                 self._idle_mark = now
         if self._idle_mark is not None:
-            gap = max(now - self._idle_mark, 0.0)
-            self.host_gap_s += gap
-            self.gap_samples.append(gap)
+            self.host_gap_s += max(now - self._idle_mark, 0.0)
+            self.n_gaps += 1
         elif self._outstanding is not None:
-            self.gap_samples.append(0.0)  # device still busy: zero gap
+            self.n_gaps += 1  # device still busy: zero gap
         self._idle_mark = None
 
-        eng._key, sk = jax.random.split(eng._key)
+    def _launch(self, kind: str, dev: dict, sk):
+        """The prev-token substitution, the step program and the sampler
+        (with key ``sk``): returns the sampled tokens' device future."""
+        eng = self.eng
         prev = (self._last.tokens if self._last is not None
                 else jnp.zeros((eng.max_batch,), jnp.int32))
-        if plan.kind == "ragged":
-            toks_in = self._subst_packed_jit(
-                jnp.asarray(plan.tokens), prev, jnp.asarray(plan.prev_slots),
-                jnp.asarray(plan.decode_idx),
-            )
-            logits, *pools = eng._ragged_step_jit(
-                eng.params, eng.kv.k, eng.kv.v, eng.kv.k_scale,
-                eng.kv.v_scale, jnp.asarray(plan.tables),
-                toks_in, jnp.asarray(plan.row_of), jnp.asarray(plan.slots),
-                jnp.asarray(plan.positions), jnp.asarray(plan.p_end),
-                jnp.asarray(plan.s_start), jnp.asarray(plan.last_idx),
-            )
-            eng._set_pools(*pools)
-        elif plan.kind == "fused":
-            toks_in = self._subst_jit(
-                jnp.asarray(plan.tokens), prev, jnp.asarray(plan.prev_slots)
-            )
-            logits, *pools = eng._fused_step_jit(
-                eng.params, eng.kv.k, eng.kv.v, eng.kv.k_scale,
-                eng.kv.v_scale, jnp.asarray(plan.tables),
-                toks_in, jnp.asarray(plan.starts), jnp.asarray(plan.n_valid),
-                jnp.asarray(plan.positions), jnp.asarray(plan.p_end),
-                jnp.asarray(plan.s_start),
-            )
-            eng._set_pools(*pools)
+        state = (eng.params, eng.kv.k, eng.kv.v, eng.kv.k_scale, eng.kv.v_scale,
+                 dev["tables"])
+        if kind == "ragged":
+            toks_in = self._subst_packed_jit(dev["tokens"], prev,
+                                             dev["prev_slots"], dev["decode_idx"])
+            logits, *out = eng._ragged_step_jit(
+                *state, toks_in, dev["row_of"], dev["slots"], dev["positions"],
+                dev["p_end"], dev["s_start"], dev["last_idx"])
+        elif kind == "fused":
+            toks_in = self._subst_jit(dev["tokens"], prev, dev["prev_slots"])
+            logits, *out = eng._fused_step_jit(
+                *state, toks_in, dev["starts"], dev["n_valid"],
+                dev["positions"], dev["p_end"], dev["s_start"])
         else:
-            toks_in = self._subst_jit(
-                jnp.asarray(plan.tokens), prev, jnp.asarray(plan.prev_slots)
-            )
-            logits, *pools = eng._decode_dispatch_jit(
-                eng.params, eng.kv.k, eng.kv.v, eng.kv.k_scale,
-                eng.kv.v_scale, jnp.asarray(plan.tables),
-                toks_in, jnp.asarray(plan.starts),
-            )
-            eng._set_pools(*pools)
-        toks = self._sample_jit(sk, logits, jnp.asarray(plan.temps))
-        ex = PlanExec(plan, toks, now)
-        self._last = ex
-        self._outstanding = ex
-        self.last_plan_id = plan.plan_id
-        self.n_dispatched += 1
-        return ex
+            toks_in = self._subst_jit(dev["tokens"], prev, dev["prev_slots"])
+            logits, *out = eng._decode_dispatch_jit(*state, toks_in,
+                                                    dev["starts"])
+        eng._set_pools(*out)
+        return self._sample_jit(sk, logits, dev["temps"])
 
     # ---------------------------------------------------------- materialize
     def materialize(self, ex: PlanExec) -> np.ndarray:
@@ -169,7 +179,8 @@ class DeviceRunner:
         When ``ex`` is the newest dispatched work, the device is idle from
         here until the next dispatch — start the gap clock."""
         if ex._host is None:
-            ex._host = np.asarray(ex.tokens)
+            with self.eng.telemetry.span("engine.materialize.wait"):
+                ex._host = np.asarray(ex.tokens)
             t = time.perf_counter()
             ex.ready_at = t
             if self._outstanding is ex:
@@ -185,9 +196,8 @@ class DeviceRunner:
 
     # ---------------------------------------------------------------- stats
     def summary(self) -> dict:
-        gaps = self.gap_samples
         return {
             "host_gap_s": self.host_gap_s,
-            "host_gap_mean_s": float(np.mean(gaps)) if gaps else 0.0,
+            "host_gap_mean_s": self.host_gap_s / self.n_gaps if self.n_gaps else 0.0,
             "dispatches": self.n_dispatched,
         }

@@ -89,6 +89,7 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.core.scheduler import QueuePolicy, make_policy
 from repro.core.streaming import PriorityFlusher, StreamingObject
+from repro.core.telemetry import Span, Telemetry
 from repro.kernels.decode_attention import default_interpret
 from repro.models import (
     decode_step,
@@ -152,7 +153,9 @@ class Request:
     swapped: bool = False            # KV chain parked in the host tier
     swap_len: int = 0                # cache length to restore on swap-in
     queued_steps: int = 0            # engine steps spent waiting for admission
+    trace_id: int = -1               # shared by the stages of one pipeline
     submitted_at: float = 0.0
+    admitted_at: Optional[float] = None  # first took a slot
     first_token_at: Optional[float] = None
     last_token_at: Optional[float] = None
     finished_at: Optional[float] = None
@@ -267,7 +270,6 @@ class GenerationEngine:
         flusher: Optional[PriorityFlusher] = None,
         host_bw_bytes_s: float = 8e9,
         copy_budget: int = 4,
-        telemetry: Any = None,
         kernel: Optional[str] = None,
         ragged: bool = True,
         pack_align: int = 4,
@@ -317,8 +319,7 @@ class GenerationEngine:
         requires the ragged layout for fused steps.
         ``flusher`` shares one PriorityFlusher across engines (DP groups);
         ``host_bw_bytes_s`` calibrates the cost model's swap estimate;
-        ``copy_budget`` bounds per-step async copy draining; ``telemetry``
-        (core.telemetry.Telemetry) receives per-step engine gauges.
+        ``copy_budget`` bounds per-step async copy draining.
 
         ``kv_dtype="int8"`` stores the paged pools quantized (per-block,
         per-KV-head absmax scales ride alongside in parallel scale pools;
@@ -368,6 +369,16 @@ class GenerationEngine:
         self.tokens_out = 0
         self.prefill_tokens = 0
         self.preemptions = 0
+        # admission: first admissions, their summed wait since submit, and
+        # the admit passes that stopped at a prefilling leader or for want
+        # of blocks
+        self.admitted = 0
+        self.admit_wait_ns = 0
+        self.admit_deferred = 0
+        self.admit_blocked = 0
+        # timed spans at the layers' boundaries (profiler annotations plus
+        # always-on totals) and one span per finished request
+        self.telemetry = Telemetry(max_series=max_finished)
         self.swap_outs = 0
         self.swap_ins = 0
         if preempt not in ("recompute", "swap", "cost"):
@@ -400,7 +411,6 @@ class GenerationEngine:
         self.host_store = host_store
         self.pipeline = bool(pipeline) and self.interleave
         self.flusher = flusher if flusher is not None else PriorityFlusher()
-        self.telemetry = telemetry
         self.host_bw_bytes_s = host_bw_bytes_s
         self.copy_budget = copy_budget
         self.cost_swap_choices = 0
@@ -512,10 +522,12 @@ class GenerationEngine:
 
     # ------------------------------------------------------------------ API
     def submit(self, prompt, max_new: int = 16, temperature: float = 0.0,
-               priority: float = 0.0) -> Request:
+               priority: float = 0.0, trace_id: Optional[int] = None) -> Request:
         """``prompt`` is a flat token array, or a ``SegmentedPrompt`` whose
         per-document segments enable order-independent KV reuse (paged
-        backend; the dense oracle flattens it)."""
+        backend; the dense oracle flattens it). ``trace_id`` files the
+        request's span under a trace shared by several requests (the stages
+        of one pipeline; the request's own id when None)."""
         segprompt = prompt if isinstance(prompt, SegmentedPrompt) else None
         if segprompt is not None:
             prompt = segprompt.tokens
@@ -524,6 +536,7 @@ class GenerationEngine:
             prompt = np.zeros(1, np.int32)  # empty prompt: decode from pad token
             segprompt = None
         req = Request(self._next_id, prompt, max_new, temperature, priority)
+        req.trace_id = req.req_id if trace_id is None else int(trace_id)
         req.segprompt = segprompt
         req.submitted_at = time.monotonic()
         # out-of-band delivery: tokens stream through a per-request
@@ -552,7 +565,7 @@ class GenerationEngine:
             self.step()
             max_steps -= 1
         self._drain_copies(full=True)
-        self.flusher.flush()
+        self._flush_streams()
 
     def stats(self) -> Dict[str, Any]:
         s: Dict[str, Any] = {
@@ -564,6 +577,11 @@ class GenerationEngine:
             "prefill_tokens": self.prefill_tokens,
             "preemptions": self.preemptions,
             "stream_backlog": self.flusher.backlog,
+            "admitted": self.admitted,
+            "admit_wait_ns": self.admit_wait_ns,
+            "admit_deferred": self.admit_deferred,
+            "admit_blocked": self.admit_blocked,
+            **self.telemetry.span_totals(),
         }
         if self.backend == "paged":
             s["utilization"] = self.kv.utilization()
@@ -572,6 +590,7 @@ class GenerationEngine:
             s["session_hit_tokens"] = self.kv.session_host_token_hits
             s["session_shared_tokens"] = self.kv.session_token_hits
             s["free_blocks"] = self.kv.pool.n_free
+            s["evictions"] = self.kv.pool.evictions
             s["measured_hit_rate"] = self.measured_hit_rate()
             s["measured_host_hit_rate"] = self.measured_host_hit_rate()
             s["measured_session_hit_rate"] = self.measured_session_hit_rate()
@@ -887,6 +906,7 @@ class GenerationEngine:
             req.truncated = True
             req.finished_at = time.monotonic()
             self.finished.append(req)
+            self._record_span(req)
             if req.stream is not None and not req.stream.closed:
                 req.stream.close()
             return False
@@ -1380,14 +1400,15 @@ class GenerationEngine:
         double-buffered). Sequential mode: admit (blocking whole-prompt
         prefill), then one batched decode. Returns the tokens whose emission
         LANDED this step — in pipelined mode that is the previous plan's."""
-        for r in self.waiting:
-            r.queued_steps += 1
-        if self.interleave:
-            return self._step_planned()
-        out = self._step_sequential()
-        self._drain_copies(full=True)
-        self.flusher.flush()
-        return out
+        with self.telemetry.span("engine.step"):
+            for r in self.waiting:
+                r.queued_steps += 1
+            if self.interleave:
+                return self._step_planned()
+            out = self._step_sequential()
+            self._drain_copies(full=True)
+            self._flush_streams()
+            return out
 
     def _step_planned(self) -> Dict[int, List[int]]:
         emitted: Dict[int, List[int]] = {}
@@ -1413,15 +1434,13 @@ class GenerationEngine:
             # before the next plan is built, so pipelining degenerates
             cur, self._inflight = self._inflight, None
             _merge_emitted(emitted, self._materialize(cur))
-        self.flusher.flush()
-        if self.telemetry is not None:
-            now = time.monotonic()
-            self.telemetry.gauge("engine/host_gap_s", now, self.runner.host_gap_s)
-            self.telemetry.gauge("engine/copy_backlog", now, self._copy.backlog)
-            if self.control.last_chunk_size is not None:
-                self.telemetry.gauge("engine/stream_chunk_size", now,
-                                     self.control.last_chunk_size)
+        self._flush_streams()
         return emitted
+
+    def _flush_streams(self) -> None:
+        """Deliver the streams' pending chunks through the flusher."""
+        with self.telemetry.span("engine.flush"):
+            self.flusher.flush()
 
     def _materialize(self, ex: PlanExec) -> Dict[int, List[int]]:
         """Land a dispatched plan's emissions: pull the sampled tokens to the
@@ -1430,12 +1449,13 @@ class GenerationEngine:
         path above)."""
         toks = self.runner.materialize(ex)
         emitted: Dict[int, List[int]] = {}
-        for req, row, finishing in ex.plan.emit_rows:
-            tok = int(toks[row])
-            self._emit_token(req, tok)
-            emitted.setdefault(req.req_id, []).append(tok)
-            if finishing or tok == self.eos_token:
-                self._finalize(req)
+        with self.telemetry.span("engine.emit"):
+            for req, row, finishing in ex.plan.emit_rows:
+                tok = int(toks[row])
+                self._emit_token(req, tok)
+                emitted.setdefault(req.req_id, []).append(tok)
+                if finishing or tok == self.eos_token:
+                    self._finalize(req)
         return emitted
 
     def _sync_inflight(self) -> None:
@@ -1463,9 +1483,10 @@ class GenerationEngine:
         """Advance the async copy engine: the whole backlog when ``full``
         (idle steps, drain/exit paths), else up to ``copy_budget`` ops —
         bounded host work per step, scheduled between dispatches."""
-        if self.backend == "paged":
-            self.kv.flush_write_through()
-        self._copy.drain(None if full else self.copy_budget)
+        with self.telemetry.span("engine.copies"):
+            if self.backend == "paged":
+                self.kv.flush_write_through()
+            self._copy.drain(None if full else self.copy_budget)
 
     def _step_sequential(self) -> Dict[int, List[int]]:
         blocked = False
@@ -1478,10 +1499,12 @@ class GenerationEngine:
                     if req.done:  # unfittable request failed out; try the next
                         self.waiting.pop(i)
                         continue
+                    self.admit_blocked += 1
                     blocked = True  # the policy's head-of-line waits for blocks
                     break
                 self.waiting.pop(i)
                 self.slots[slot] = req
+                self._mark_admitted(req)
                 if was_swapped:
                     # restored in place: KV, position and cursor resume as
                     # they were (sequential victims are always decode-phase)
@@ -1566,6 +1589,23 @@ class GenerationEngine:
                 self.slots[r.slot] = None
         return emitted
 
+    def _mark_admitted(self, req: Request) -> None:
+        """A request took a slot: the first time, stamp ``admitted_at`` and
+        count its wait since submit (a restore after preemption keeps the
+        first stamp)."""
+        if req.admitted_at is None:
+            req.admitted_at = time.monotonic()
+            self.admitted += 1
+            self.admit_wait_ns += int((req.admitted_at - req.submitted_at) * 1e9)
+
+    def _record_span(self, req: Request) -> None:
+        """The finished request's span: queued from submit, served from
+        admission (never admitted: from its end) to its end."""
+        end = req.finished_at
+        start = req.admitted_at if req.admitted_at is not None else end
+        self.telemetry.record_span(Span(req.trace_id, "engine", 0,
+                                        req.submitted_at, start, end))
+
     def _emit_token(self, req: Request, tok: int):
         """Emission side effects of one materialized token: timestamps,
         out_tokens, counters, and the out-of-band stream write."""
@@ -1591,6 +1631,7 @@ class GenerationEngine:
         req.finished_at = (req.last_token_at if req.last_token_at is not None
                            else time.monotonic())
         self.finished.append(req)
+        self._record_span(req)
         if len(self.finished) > self.max_finished:
             del self.finished[: -self.max_finished]
         if req.slot >= 0 and self.slots[req.slot] is req:
@@ -1701,12 +1742,12 @@ class DataParallelEngineGroup:
             self.engines.append(eng)
 
     def submit(self, prompt, max_new: int = 16, temperature: float = 0.0,
-               priority: float = 0.0) -> Request:
+               priority: float = 0.0, trace_id: Optional[int] = None) -> Request:
         eng = min(
             self.engines,
             key=lambda e: len(e.waiting) + sum(s is not None for s in e.slots),
         )
-        return eng.submit(prompt, max_new, temperature, priority)
+        return eng.submit(prompt, max_new, temperature, priority, trace_id)
 
     def step(self) -> None:
         for eng in self.engines:

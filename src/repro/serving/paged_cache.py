@@ -76,6 +76,7 @@ class PagedPool:
     on_free: Optional[Callable[[int], None]] = None             # block truly freed
     keep_on_release: Optional[Callable[[int], bool]] = None     # warm-cache policy
     n_owned: int = 0     # blocks this allocator may hand out (DP block range)
+    evictions: int = 0   # warm blocks that allocation took back
     # optional analysis.kvsan.KVSanitizer: every state transition below
     # mirrors into its shadow machine, which raises on lifecycle violations
     # (use-after-free, double-free, refcount underflow). None = no overhead.
@@ -106,6 +107,7 @@ class PagedPool:
             raise MemoryError("paged pool exhausted: no free or warm block")
         b = next(iter(self.cached))  # evict least-recently-used warm block
         del self.cached[b]
+        self.evictions += 1
         if self.sanitizer is not None:
             self.sanitizer.device_warm_evict(b)
         if self.on_free is not None:

@@ -52,9 +52,14 @@ def _operands(one_chip, arch, pool_dtype, n_q):
     return s((n_q, H, hd), q_dtype), pool, s, scales
 
 
-def _assert_kernel_compiled(fn, *args, **kw):
-    compiled = jax.jit(fn).lower(*args, **kw).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+def _assert_kernel_compiled(fn, name, *args, **kw):
+    """The kernel compiles to a Mosaic custom call that carries its own
+    name (``%<name>.N = ... custom_call_target="tpu_custom_call"``)."""
+    text = jax.jit(fn).lower(*args, **kw).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert any(line.lstrip().startswith(f"%{name}")
+               and 'custom_call_target="tpu_custom_call"' in line
+               for line in text.splitlines()), name
 
 
 @pytest.mark.parametrize("pool_dtype", POOL_DTYPES)
@@ -67,7 +72,7 @@ def test_paged_decode_compiles(one_chip, arch, pool_dtype):
                                       k_scale=k_scale, v_scale=v_scale,
                                       interpret=False)
 
-    _assert_kernel_compiled(decode, q, pool, pool,
+    _assert_kernel_compiled(decode, "paged_decode_attention", q, pool, pool,
                             s((BATCH, MAX_BLOCKS), jnp.int32),
                             s((BATCH,), jnp.int32), **scales)
 
@@ -84,7 +89,7 @@ def test_paged_chunk_compiles(one_chip, arch, pool_dtype):
                                      s_start, k_scale=k_scale,
                                      v_scale=v_scale, interpret=False)
 
-    _assert_kernel_compiled(chunk, q, pool, pool,
+    _assert_kernel_compiled(chunk, "paged_chunk_attention", q, pool, pool,
                             s((BATCH, MAX_BLOCKS), jnp.int32),
                             per_token, per_token, per_token, per_token,
                             **scales)
