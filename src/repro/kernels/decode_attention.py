@@ -20,9 +20,11 @@ engine's XLA decode path) are checked against.
 tokens from B sequences (decode rows and prefill chunks mixed in one flat
 buffer) each attend their own sequence's paged KV through the shared block
 table, with the segmented-prompt span mask (prelude + own segment + causal
-self) applied inside the kernel. One query token per grid row keeps the
-q tile at the decode kernel's (KVH, G, hd) shape regardless of how the
-batch is packed, so ragged layouts cost no padding FLOPs at all.
+self) applied inside the kernel per query row. Its grid cell is one (query
+tile, KV block) pair: a tile holds up to ``tq`` tokens of one row, tq·G
+query rows filling the MXU (a decode row is a tile of one token), so a
+prefill chunk streams its sequence's KV once a tile instead of once a
+token, and a tile reads only the blocks up to its last attended slot.
 
 Both kernels tolerate RAW block tables: pad entries (-1) are masked inside
 the kernel (index_maps clamp them to block 0 purely so the DMA has a legal
@@ -38,6 +40,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -350,32 +353,86 @@ def ref_paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
 # ---------------------------------------------------------------------------
 # packed (ragged fused-step) chunk attention
 # ---------------------------------------------------------------------------
+#
+# Query tiling: the packed tokens of one batch row are taken ``tq`` at a time
+# (in packed order) as one query tile, and a grid cell is one (tile, logical
+# KV block) pair, so a block is DMA'd once for up to ``tq`` tokens. Tokens
+# are grouped by row before they are tiled, so a row whose tokens sit in
+# separate runs of the flat buffer still makes ceil(count / tq) tiles, and
+# the tile count stays under ceil(T / tq) + min(B, T) whatever the packing.
+
+_MXU_ROWS = 128
 
 
-def _paged_chunk_kernel(tab_ref, row_ref, slot_ref, pend_ref, sstart_ref,
-                        q_ref, k_ref, v_ref, *rest, block_size: int, nkv: int,
-                        kvh: int, scale: float, quantized: bool = False):
+def chunk_query_tile(groups: int) -> int:
+    """Query tokens per tile of ``paged_chunk_attention`` for ``groups``
+    query heads per KV head: the most whose ``tq * groups`` query rows fit
+    the MXU's 128 rows, as a multiple of 8 and at least 8."""
+    return max(8, _MXU_ROWS // groups // 8 * 8)
+
+
+def chunk_tile_count(row_of, groups: int) -> int:
+    """The query tiles ``paged_chunk_attention`` launches for the packed
+    tokens ``row_of`` (-1 = pad, in no tile): ceil(count / tq) per row."""
+    row_of = np.asarray(row_of)
+    counts = np.bincount(row_of[row_of >= 0])
+    tq = chunk_query_tile(groups)
+    return int((-(-counts // tq)).sum())
+
+
+def _max_tiles(T: int, n_rows: int, tq: int) -> int:
+    """Static bound on the tiles of T packed tokens of ``n_rows`` rows:
+    sum(ceil(count / tq)) <= ceil(T / tq) + rows - 1, and a tile holds a
+    token."""
+    return min(T, -(-T // tq) + min(n_rows, T) - 1)
+
+
+def _chunk_tiles(row_of, n_rows: int, tq: int):
+    """Device side of the tiling. Returns per token its tile (the bound
+    ``_max_tiles`` for a pad) and lane within the tile, and the number of
+    tiles."""
+    n_max = _max_tiles(row_of.shape[0], n_rows, tq)
+    live = row_of >= 0
+    hot = (row_of[:, None] == jnp.arange(n_rows)[None]).astype(jnp.int32)
+    rank = jnp.sum(jnp.cumsum(hot, axis=0) * hot, axis=1) - 1   # within row
+    row_tiles = (jnp.sum(hot, axis=0) + tq - 1) // tq           # (B,)
+    first = jnp.cumsum(row_tiles) - row_tiles
+    tile = jnp.where(live, first[jnp.maximum(row_of, 0)] + rank // tq, n_max)
+    lane = jnp.maximum(rank, 0) % tq
+    return tile, lane, jnp.sum(row_tiles).reshape(1)
+
+
+def _paged_chunk_kernel(tab_ref, row_ref, last_ref, n_ref, slot_ref, pend_ref,
+                        sstart_ref, q_ref, k_ref, v_ref, *rest,
+                        block_size: int, nkv: int, kvh: int, scale: float,
+                        quantized: bool = False):
     ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = _split_rest(rest, quantized)
-    t = pl.program_id(0)   # packed token
+    i = pl.program_id(0)   # query tile
     j = pl.program_id(1)   # logical kv block
 
     @pl.when(j == 0)
     def _init():
         _init_scratch(acc_ref, m_ref, l_ref)
 
-    # the segmented-prompt span mask (models.transformer.apply_layer_prefix):
-    # a token attends the shared prelude (slot < p_end) plus its own document
-    # segment up to itself (s_start <= slot <= own slot); flat prompts and
-    # decode rows pass p_end = s_start = 0, degenerating to plain causal.
-    # Raw -1 table entries and packed pad tokens (row_of < 0) mask to -inf.
-    kpos = j * block_size + jax.lax.broadcasted_iota(jnp.int32, (1, block_size), 1)
-    row = row_ref[t]
-    backed = (row >= 0) & (tab_ref[jnp.maximum(row, 0), j] >= 0)
-    span = (kpos < pend_ref[t]) | (
-        (kpos >= sstart_ref[t]) & (kpos <= slot_ref[t])
-    )
-    _online_softmax_heads(q_ref, k_ref, v_ref, ks_ref, vs_ref, acc_ref,
-                          m_ref, l_ref, backed & span, kvh=kvh, scale=scale)
+    # past the tile's last attended block (or on a tile with no tokens) the
+    # index_maps repeat the previous block, so there is no DMA; skip the math
+    @pl.when((i < n_ref[0]) & (j <= last_ref[i]))
+    def _update():
+        # the segmented-prompt span mask (models.transformer.apply_layer_prefix)
+        # per query row: the shared prelude (slot < p_end) plus the token's
+        # own document segment up to itself (s_start <= slot <= own slot);
+        # flat prompts and decode rows pass p_end = s_start = 0, plain causal.
+        # A query row with no token has slot -1 and masks everything; raw -1
+        # table entries mask the whole block.
+        kpos = j * block_size + jax.lax.broadcasted_iota(
+            jnp.int32, (1, block_size), 1)
+        backed = tab_ref[row_ref[i], j] >= 0
+        span = (kpos < pend_ref[0]) | (
+            (kpos >= sstart_ref[0]) & (kpos <= slot_ref[0])
+        )                                            # (tq * G, bs)
+        _online_softmax_heads(q_ref, k_ref, v_ref, ks_ref, vs_ref, acc_ref,
+                              m_ref, l_ref, backed & span, kvh=kvh,
+                              scale=scale)
 
     @pl.when(j == nkv - 1)
     def _done():
@@ -399,67 +456,107 @@ def paged_chunk_attention(
     segmented-prompt attention spans (zeros = plain causal over slots).
     Returns (T, H, hd).
 
-    Grid (T, max_blocks): one query token per cell row keeps the q tile at
-    (KVH, G, hd) — the decode kernel's shape — so the kernel is indifferent
-    to how rows were packed; ``block_tables[row_of[t]]`` drives the K/V
-    index_map through scalar prefetch. ``k_scale``/``v_scale`` ((n_blocks,
-    KVH) float32) mark an int8 pool, dequantized in VMEM after the block DMA.
+    Grid (n_tiles_max, max_blocks), one cell per (query tile, KV block). A
+    tile is up to ``tq = chunk_query_tile(G)`` tokens of one row, so its
+    (KVH, tq·G, hd) q block fills the MXU's rows; a decode row is a tile
+    with one token. The tiles are derived here from ``row_of`` (small XLA
+    gathers in, one gather out); tile ``i`` streams the blocks
+    ``block_tables[row][0 .. last_col[i]]`` that its tokens can attend
+    (up to max(slot, p_end - 1)), and each block is DMA'd once for the whole
+    tile. Later columns, and tiles with no tokens, map to the block already
+    in VMEM and skip the online-softmax update. The span mask is each
+    token's own, per query row. ``k_scale``/``v_scale`` ((n_blocks, KVH)
+    float32) mark an int8 pool, dequantized in VMEM after the block DMA.
     """
     T, H, hd = q.shape
     nb, bs, KVH = k_pool.shape[0], k_pool.shape[1], k_pool.shape[2]
     G = H // KVH
-    mb = block_tables.shape[1]
+    B, mb = block_tables.shape
     scale = scale if scale is not None else 1.0 / math.sqrt(hd)
     quantized = k_scale is not None
+    tq = chunk_query_tile(G)
+    n_max = _max_tiles(T, B, tq)
 
-    qf = q.reshape(T, KVH, G, hd)
+    row_of = jnp.asarray(row_of, jnp.int32)
+    slots = jnp.asarray(slots, jnp.int32)
+    p_end = jnp.asarray(p_end, jnp.int32)
+    s_start = jnp.asarray(s_start, jnp.int32)
+    tile, lane, n_tiles = _chunk_tiles(row_of, B, tq)
+    tok = jnp.full((n_max, tq), -1, jnp.int32).at[tile, lane].set(
+        jnp.arange(T, dtype=jnp.int32), mode="drop")
+    tile_row = jnp.zeros((n_max,), jnp.int32).at[tile].set(row_of, mode="drop")
+    last_slot = jnp.zeros((n_max,), jnp.int32).at[tile].max(
+        jnp.maximum(slots, p_end - 1), mode="drop")
+    last_col = jnp.minimum(last_slot // bs, mb - 1)
+
+    has_tok = tok >= 0
+    src = jnp.maximum(tok, 0)
+
+    def per_row(x, empty):   # (T,) -> (n_max, tq * G, 1), one value a q row
+        x = jnp.where(has_tok, x[src], empty)
+        return jnp.repeat(x, G, axis=1)[..., None]
+
+    qt = q.reshape(T, KVH, G, hd)[src]                # (n_max, tq, KVH, G, hd)
+    qt = qt.transpose(0, 2, 1, 3, 4).reshape(n_max, KVH, tq * G, hd)
     tables = jnp.asarray(block_tables, jnp.int32)
 
-    def q_map(t, j, tab_ref, row_ref, slot_ref, pend_ref, sstart_ref):
-        return (t, 0, 0, 0)
+    def q_map(i, j, *_):
+        return (i, 0, 0, 0)
 
-    def block_of(t, j, tab_ref, row_ref):
-        return jnp.maximum(tab_ref[jnp.maximum(row_ref[t], 0), j], 0)
+    def row_map(i, j, *_):
+        return (i, 0, 0)
 
-    def kv_map(t, j, tab_ref, row_ref, slot_ref, pend_ref, sstart_ref):
-        return (block_of(t, j, tab_ref, row_ref), 0, 0, 0)
+    def block_of(i, j, tab_ref, row_ref, last_ref, n_ref):
+        # a tile with no tokens repeats the last live cell's block
+        src = jnp.minimum(i, jnp.maximum(n_ref[0] - 1, 0))
+        col = jnp.where(i < n_ref[0], jnp.minimum(j, last_ref[src]),
+                        last_ref[src])
+        return jnp.maximum(tab_ref[row_ref[src], col], 0)
 
-    def sc_map(t, j, tab_ref, row_ref, slot_ref, pend_ref, sstart_ref):
-        return (block_of(t, j, tab_ref, row_ref), 0, 0)
+    def kv_map(i, j, *refs):
+        return (block_of(i, j, *refs), 0, 0, 0)
+
+    def sc_map(i, j, *refs):
+        return (block_of(i, j, *refs), 0, 0)
 
     kernel = functools.partial(
         _paged_chunk_kernel, block_size=bs, nkv=mb, kvh=KVH, scale=scale,
         quantized=quantized,
     )
     in_specs = [
-        pl.BlockSpec((1, KVH, G, hd), q_map),
+        pl.BlockSpec((1, tq * G, 1), row_map),
+        pl.BlockSpec((1, tq * G, 1), row_map),
+        pl.BlockSpec((1, tq * G, 1), row_map),
+        pl.BlockSpec((1, KVH, tq * G, hd), q_map),
         pl.BlockSpec((1, bs, KVH, hd), kv_map),
         pl.BlockSpec((1, bs, KVH, hd), kv_map),
     ]
     operands = [
-        tables, jnp.asarray(row_of, jnp.int32), jnp.asarray(slots, jnp.int32),
-        jnp.asarray(p_end, jnp.int32), jnp.asarray(s_start, jnp.int32),
-        qf, k_pool, v_pool,
+        tables, tile_row, last_col, n_tiles,
+        per_row(slots, -1), per_row(p_end, 0), per_row(s_start, 0),
+        qt, k_pool, v_pool,
     ]
     if quantized:
         specs, scales = _scale_specs(k_scale, v_scale, nb, KVH, sc_map)
         in_specs += specs
         operands += scales
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
-        grid=(T, mb),
+        num_scalar_prefetch=4,
+        grid=(n_max, mb),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, KVH, G, hd), q_map),
-        scratch_shapes=_scratch(KVH, G, hd),
+        out_specs=pl.BlockSpec((1, KVH, tq * G, hd), q_map),
+        scratch_shapes=_scratch(KVH, tq * G, hd),
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((T, KVH, G, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((n_max, KVH, tq * G, hd), q.dtype),
         interpret=interpret,
         name="paged_chunk_attention",
     )(*operands)
-    return out.reshape(T, KVH * G, hd)
+    out = out.reshape(n_max, KVH, tq, G, hd).transpose(0, 2, 1, 3, 4)
+    flat = jnp.where(row_of >= 0, tile * tq + lane, 0)
+    return out.reshape(n_max * tq, H, hd)[flat]
 
 
 def ref_paged_chunk_attention(q, k_pool, v_pool, block_tables, row_of, slots,

@@ -44,6 +44,7 @@ from typing import Any, Callable, Deque, Dict, List, Optional, Tuple
 import numpy as np
 
 from repro.core.streaming import streaming_chunk_policy
+from repro.kernels.decode_attention import chunk_tile_count
 
 
 @dataclass(frozen=True, eq=False)
@@ -411,15 +412,18 @@ class ControlPlane:
         emit, n_tok = self._mixed_bookkeeping(
             plan_id, prefill_rows, decode_rows, grants
         )
+        row_of = flat(row_l, fill=-1)
         eng.fused_slot_tokens += T_pad
         eng.fused_valid_tokens += cursor
+        eng.chunk_tiles += chunk_tile_count(
+            row_of, eng.cfg.num_heads // eng.cfg.num_kv_heads)
         return StepPlan(
             plan_id=plan_id, kind="ragged", tokens=flat(toks),
             starts=starts, temps=temps, tables=tables, prev_slots=prev_slots,
             emit_rows=tuple(emit), n_tokens=n_tok, n_valid=n_valid,
             positions=flat(pos_l), p_end=flat(pend_l),
             s_start=flat(sstart_l),
-            row_of=flat(row_l, fill=-1),
+            row_of=row_of,
             slots=flat(slot_l), decode_idx=decode_idx, last_idx=last_idx,
         )
 

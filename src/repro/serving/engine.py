@@ -408,6 +408,9 @@ class GenerationEngine:
         # ragged layout exists to remove
         self.fused_slot_tokens = 0
         self.fused_valid_tokens = 0
+        # query tiles the ragged steps' chunk attention launches
+        # (fused_valid_tokens / chunk_tiles is tokens per tile)
+        self.chunk_tiles = 0
         self.host_store = host_store
         self.pipeline = bool(pipeline) and self.interleave
         self.flusher = flusher if flusher is not None else PriorityFlusher()
@@ -601,6 +604,7 @@ class GenerationEngine:
             s["ragged"] = self.ragged
             s["fused_slot_tokens"] = self.fused_slot_tokens
             s["fused_valid_tokens"] = self.fused_valid_tokens
+            s["chunk_tiles"] = self.chunk_tiles
             s["padded_token_fraction"] = (
                 1.0 - self.fused_valid_tokens / self.fused_slot_tokens
                 if self.fused_slot_tokens else 0.0

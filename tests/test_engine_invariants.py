@@ -248,6 +248,40 @@ def test_pallas_kernel_rejects_unsupported_modes():
         GenerationEngine(cfg, kernel="mosaic-gpu")
 
 
+def test_chunk_tiles_counts_the_kernels_query_tiles():
+    """``stats()["chunk_tiles"]`` sums, over the ragged steps, the query
+    tiles ``paged_chunk_attention`` derives on device from each plan's
+    packed ``row_of``."""
+    import jax.numpy as jnp
+
+    from repro.kernels.decode_attention import _chunk_tiles, chunk_query_tile
+
+    cfg = _cfg()
+    g = cfg.num_heads // cfg.num_kv_heads
+    eng = GenerationEngine(cfg, max_batch=3, max_seq=96, n_blocks=24,
+                           prefill_chunk_size=16, token_budget=20,
+                           kernel="pallas")
+    plans = []
+    assemble = eng.control._assemble_ragged
+
+    def spy(*args):
+        plans.append(assemble(*args))
+        return plans[-1]
+
+    eng.control._assemble_ragged = spy
+    rng = np.random.default_rng(3)
+    for n in (30, 5, 12):
+        eng.submit(rng.integers(0, 90, size=n), max_new=3)
+    eng.run_until_done(max_steps=100)
+    assert plans
+    want = 0
+    for p in plans:
+        _, _, n = _chunk_tiles(jnp.asarray(p.row_of), eng.max_batch,
+                               chunk_query_tile(g))
+        want += int(n[0])
+    assert eng.stats()["chunk_tiles"] == want > len(plans)
+
+
 # ------------------------------------------------------------ int8 KV pools
 def _greedy_agreement(reqs_a, reqs_b) -> float:
     match = total = 0

@@ -9,6 +9,7 @@ and non-power-of-two head dims. Every case runs in interpret mode (the CPU CI
 path); a mirrored compiled-mode sweep runs only where Mosaic lowering exists
 (TPU) and is skipped elsewhere.
 """
+import functools
 import math
 
 import jax
@@ -17,6 +18,10 @@ import numpy as np
 import pytest
 
 from repro.kernels.decode_attention import (
+    _chunk_tiles,
+    _max_tiles,
+    chunk_query_tile,
+    chunk_tile_count,
     paged_chunk_attention,
     paged_decode_attention,
     ref_paged_chunk_attention,
@@ -114,12 +119,12 @@ def _assert_decode_matches(case, interpret):
 
 
 def _assert_chunk_matches(case, interpret):
+    # jitted whole: one compile a case instead of one per eager op
     q, kp, vp, tables, row_of, slots, p_end, s_start = case
-    got = paged_chunk_attention(q, kp, vp, tables, row_of, slots, p_end,
-                                s_start, interpret=interpret)
+    got = jax.jit(functools.partial(paged_chunk_attention,
+                                    interpret=interpret))(*case)
     with jax.default_matmul_precision("highest"):
-        want = ref_paged_chunk_attention(q, kp, vp, tables, row_of, slots,
-                                         p_end, s_start)
+        want = jax.jit(ref_paged_chunk_attention)(*case)
     valid = np.asarray(row_of) >= 0
     got, want = np.asarray(got), np.asarray(want)
     assert np.all(np.isfinite(got)), "pad rows must be garbage-but-FINITE"
@@ -208,6 +213,127 @@ def test_paged_chunk_raw_minus_one_tables():
     _assert_chunk_matches(case, interpret=True)
 
 
+# ------------------------------------------ chunk: query tiles of tq tokens
+def _packed_case(rng, runs, *, g, kvh=2, hd=32, bs=8, n_pad=0, holes=None,
+                 segs=None):
+    """A packed buffer laid out run by run. ``runs``: (row, first slot,
+    tokens) in packed order, a row may recur; ``n_pad`` pad tokens close
+    it. Each row's table holds its blocks plus one allocated block past its
+    last slot and two -1 columns after that (dead columns of every tile);
+    ``holes`` {row: [cols]} punches -1 entries; ``segs`` {row: (prelude
+    end, [doc starts])} gives the row segmented spans."""
+    ends = {}
+    for row, s0, n in runs:
+        ends[row] = max(ends.get(row, 0), s0 + n)
+    B = max(ends, default=0) + 1
+    lengths = [ends.get(b, 0) for b in range(B)]
+    mb = -(-max(lengths + [1]) // bs) + 3
+    n_blocks = B * mb + 1
+    kp, vp = _make_pool(rng, n_blocks, bs, kvh, hd)
+    tables = np.full((B, mb), -1, np.int32)
+    free = list(rng.permutation(n_blocks))
+    for b, ln in enumerate(lengths):
+        for j in range(-(-ln // bs) + 1 if ln else 0):
+            tables[b, j] = free.pop()
+    for b, cols in (holes or {}).items():
+        tables[b, cols] = -1
+    row_of, slots, p_end, s_start = [], [], [], []
+    for row, s0, n in runs:
+        for s in range(s0, s0 + n):
+            row_of.append(row)
+            slots.append(s)
+            pe, ss = 0, 0
+            if row in (segs or {}):
+                prelude, docs = segs[row]
+                if s >= prelude:
+                    pe, ss = prelude, max(d for d in docs if d <= s)
+            p_end.append(pe)
+            s_start.append(ss)
+    row_of += [-1] * n_pad
+    slots += [0] * n_pad
+    p_end += [0] * n_pad
+    s_start += [0] * n_pad
+    q = rng.standard_normal((len(row_of), kvh * g, hd)).astype(np.float32)
+    mk = lambda xs: jnp.asarray(np.asarray(xs, np.int32))
+    return (jnp.asarray(q), kp, vp, jnp.asarray(tables), mk(row_of),
+            mk(slots), mk(p_end), mk(s_start))
+
+
+# each layout is given the query tile tq and returns _packed_case's keywords
+TILE_LAYOUTS = {
+    # one prefill run over two whole tiles and a part of a third
+    "split_run": lambda tq: dict(runs=[(0, 5, 2 * tq + 3)]),
+    # a run of exactly one tile, then a decode row
+    "exact_tile": lambda tq: dict(runs=[(0, 0, tq), (1, 9, 1)]),
+    # one-token decode runs between prefill runs, pads at the tail
+    "decode_between": lambda tq: dict(
+        runs=[(0, 3, tq + 2), (1, 12, 1), (2, 30, 1), (3, 0, 5), (4, 17, 1)],
+        n_pad=3),
+    # row 0 in two separate runs, together longer than a tile
+    "row_in_two_runs": lambda tq: dict(
+        runs=[(0, 2, 4), (1, 7, 1), (0, 6, tq)]),
+    # nothing but pad tokens
+    "all_pad": lambda tq: dict(runs=[], n_pad=8),
+    # prelude of 3, then documents; the one from tq - 2 crosses the tile
+    # boundary at tq
+    "segments_cross_tile": lambda tq: dict(
+        runs=[(0, 0, 2 * tq), (1, 20, 1)],
+        segs={0: (3, [3, tq - 2, tq + 5])}),
+    # -1 holes inside the attended columns, beside dead columns past each
+    # tile's last slot (the row's spare allocated block, then -1 columns)
+    "holes_and_dead_columns": lambda tq: dict(
+        runs=[(0, 25, 5), (1, 16, 1), (0, 30, tq)],
+        holes={0: [1], 1: [0]}),
+}
+
+
+@pytest.mark.parametrize("layout", sorted(TILE_LAYOUTS))
+@pytest.mark.parametrize("g", [1, 2, 3, 4, 8])
+def test_paged_chunk_query_tiles(g, layout):
+    """Every query-tile geometry against the oracle at every
+    shape-derived tile width (tq = 128, 64, 40, 32, 16 for G = 1..8)."""
+    rng = np.random.default_rng(g * 100 + sorted(TILE_LAYOUTS).index(layout))
+    case = _packed_case(rng, g=g, **TILE_LAYOUTS[layout](chunk_query_tile(g)))
+    _assert_chunk_matches(case, interpret=True)
+
+
+@pytest.mark.parametrize("g", [1, 2, 3, 4, 8])
+def test_chunk_query_tile_fills_mxu_rows(g):
+    tq = chunk_query_tile(g)
+    assert tq % 8 == 0 and tq >= 8 and tq * g <= 128 < (tq + 8) * g
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_chunk_tile_count_matches_device_tiling(seed):
+    """The host count behind ``engine.stats()["chunk_tiles"]`` equals the
+    tiles the kernel derives on device, on random buffers of runs, pads and
+    rows split across runs; every tile holds up to tq tokens of one row,
+    and the count stays under the grid's static bound."""
+    rng = np.random.default_rng(700 + seed)
+    for _ in range(4):
+        B, T = int(rng.integers(1, 9)), int(rng.integers(1, 161))
+        g = int(rng.choice([1, 2, 3, 4, 8]))
+        tq = chunk_query_tile(g)
+        n_max = _max_tiles(T, B, tq)
+        row_of = np.full(T, -1, np.int32)
+        t = 0
+        while t < T:
+            n = int(rng.integers(1, 40))
+            row_of[t:t + n] = int(rng.integers(-1, B))
+            t += n
+        tile, lane, n_tiles = jax.jit(_chunk_tiles, static_argnums=(1, 2))(
+            jnp.asarray(row_of), B, tq)
+        tile, lane = np.asarray(tile), np.asarray(lane)
+        assert int(n_tiles[0]) == chunk_tile_count(row_of, g) <= n_max
+        live = row_of >= 0
+        assert np.all(tile[~live] == n_max)
+        assert np.all(tile[live] < int(n_tiles[0]))
+        pairs = set(zip(tile[live].tolist(), lane[live].tolist()))
+        assert len(pairs) == live.sum()                # one lane a token
+        for i in set(tile[live].tolist()):
+            assert len(set(row_of[tile == i].tolist())) == 1
+
+
 # --------------------------------------------------- quantized (int8) pools
 def _quantize_pool(kp, vp):
     """Per-(block, KV-head) absmax int8 quantization in the pool storage
@@ -292,6 +418,29 @@ def test_paged_chunk_quantized_pool(seed):
     np.testing.assert_allclose(got[valid], np.asarray(want_fp)[valid], **QTOL)
 
 
+@pytest.mark.parametrize("layout", sorted(TILE_LAYOUTS))
+def test_paged_chunk_query_tiles_quantized(layout):
+    """The query tiles over an int8 pool: exact against the oracle on the
+    dequantized pool, within QTOL of the float32 one."""
+    g = 4
+    rng = np.random.default_rng(900 + sorted(TILE_LAYOUTS).index(layout))
+    case = _packed_case(rng, g=g, **TILE_LAYOUTS[layout](chunk_query_tile(g)))
+    q, kp, vp, tables, row_of, slots, p_end, s_start = case
+    kq, ks, vq, vs = _quantize_pool(kp, vp)
+    got = np.asarray(jax.jit(functools.partial(
+        paged_chunk_attention, interpret=True))(
+        q, jnp.asarray(kq), jnp.asarray(vq), tables, row_of, slots, p_end,
+        s_start, k_scale=jnp.asarray(ks), v_scale=jnp.asarray(vs)))
+    ref = jax.jit(ref_paged_chunk_attention)
+    want_dq = ref(q, _dequant(kq, ks), _dequant(vq, vs), tables, row_of,
+                  slots, p_end, s_start)
+    want_fp = ref(q, kp, vp, tables, row_of, slots, p_end, s_start)
+    valid = np.asarray(row_of) >= 0
+    assert np.all(np.isfinite(got)), "pad rows must be garbage-but-FINITE"
+    np.testing.assert_allclose(got[valid], np.asarray(want_dq)[valid], **TOL)
+    np.testing.assert_allclose(got[valid], np.asarray(want_fp)[valid], **QTOL)
+
+
 # -------------------------------------------------------------- compiled mode
 @pytest.mark.skipif(not ON_TPU, reason="compiled Mosaic kernels need a TPU")
 @pytest.mark.parametrize("seed", range(2))
@@ -308,4 +457,14 @@ def test_paged_chunk_compiled(seed):
     rng = np.random.default_rng(300 + seed)
     case = _chunk_case(rng, B=4, kvh=2, g=2, hd=64, bs=16, mb=4,
                        n_blocks=32, pad_tokens=2)
+    _assert_chunk_matches(case, interpret=False)
+
+
+@pytest.mark.skipif(not ON_TPU, reason="compiled Mosaic kernels need a TPU")
+@pytest.mark.parametrize("layout", sorted(TILE_LAYOUTS))
+@pytest.mark.parametrize("g", [1, 3, 8])
+def test_paged_chunk_query_tiles_compiled(g, layout):
+    rng = np.random.default_rng(600 + g)
+    case = _packed_case(rng, g=g, hd=128, bs=16,
+                        **TILE_LAYOUTS[layout](chunk_query_tile(g)))
     _assert_chunk_matches(case, interpret=False)

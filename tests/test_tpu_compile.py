@@ -9,15 +9,19 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+import dataclasses
+
 from repro.configs import get_arch
 from repro.kernels.decode_attention import (
     paged_chunk_attention,
     paged_decode_attention,
 )
+from repro.models.model import init_params, prefill_packed
 
-ARCHS = ["qwen2.5-3b", "smollm-135m"]
+ARCHS = ["qwen2.5-3b", "smollm-135m", "phi3-medium-14b"]
 POOL_DTYPES = ["bfloat16", "int8", "float32"]
-N_BLOCKS, BLOCK, BATCH, MAX_BLOCKS, PACKED = 64, 16, 4, 8, 32
+N_BLOCKS, BLOCK, BATCH, MAX_BLOCKS = 64, 16, 4, 8
+PACKED = [32, 128]
 
 
 @pytest.fixture(scope="module")
@@ -53,13 +57,14 @@ def _operands(one_chip, arch, pool_dtype, n_q):
 
 
 def _assert_kernel_compiled(fn, name, *args, **kw):
-    """The kernel compiles to a Mosaic custom call that carries its own
-    name (``%<name>.N = ... custom_call_target="tpu_custom_call"``)."""
+    """The program compiles to exactly one Mosaic custom call, the kernel,
+    carrying its own name (``%<name>.N = ...
+    custom_call_target="tpu_custom_call"``)."""
     text = jax.jit(fn).lower(*args, **kw).compile().as_text()
-    assert "tpu_custom_call" in text
-    assert any(line.lstrip().startswith(f"%{name}")
-               and 'custom_call_target="tpu_custom_call"' in line
-               for line in text.splitlines()), name
+    calls = [line.lstrip() for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1, calls
+    assert calls[0].startswith(f"%{name}"), calls[0]
 
 
 @pytest.mark.parametrize("pool_dtype", POOL_DTYPES)
@@ -77,11 +82,12 @@ def test_paged_decode_compiles(one_chip, arch, pool_dtype):
                             s((BATCH,), jnp.int32), **scales)
 
 
+@pytest.mark.parametrize("packed", PACKED)
 @pytest.mark.parametrize("pool_dtype", POOL_DTYPES)
 @pytest.mark.parametrize("arch", ARCHS)
-def test_paged_chunk_compiles(one_chip, arch, pool_dtype):
-    q, pool, s, scales = _operands(one_chip, arch, pool_dtype, PACKED)
-    per_token = s((PACKED,), jnp.int32)
+def test_paged_chunk_compiles(one_chip, arch, pool_dtype, packed):
+    q, pool, s, scales = _operands(one_chip, arch, pool_dtype, packed)
+    per_token = s((packed,), jnp.int32)
 
     def chunk(q, k, v, tables, row_of, slots, p_end, s_start, k_scale=None,
               v_scale=None):
@@ -93,3 +99,40 @@ def test_paged_chunk_compiles(one_chip, arch, pool_dtype):
                             s((BATCH, MAX_BLOCKS), jnp.int32),
                             per_token, per_token, per_token, per_token,
                             **scales)
+
+
+@pytest.mark.parametrize("pool_dtype", ["bfloat16", "int8"])
+@pytest.mark.parametrize("arch", ARCHS)
+def test_ragged_step_has_one_chunk_kernel(one_chip, arch, pool_dtype):
+    """The whole ragged step (embedding, the layer scan, the head) at the
+    published attention widths, cut to two layers and a small vocabulary:
+    the chunk kernel's tiling adds XLA gathers around it and no second
+    custom call, so a trace finds the kernel as the step's only one."""
+    cfg = dataclasses.replace(get_arch(arch), num_layers=2, vocab_size=512)
+    KVH, hd, T = cfg.num_kv_heads, cfg.head_dim, PACKED[-1]
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda x: s(x.shape, x.dtype),
+        jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0))))
+    pool = s((cfg.num_layers, N_BLOCKS, BLOCK, KVH, hd),
+             jnp.int8 if pool_dtype == "int8" else cfg.dtype)
+    scales = s((cfg.num_layers, N_BLOCKS, KVH), jnp.float32)
+    quantized = pool_dtype == "int8"
+    per_token = s((T,), jnp.int32)
+
+    def step(params, k, v, k_sc, v_sc, tables, tokens, row_of, slots,
+             positions, p_end, s_start):
+        return prefill_packed(cfg, params, k, v, tables, tokens, row_of,
+                              slots, positions, p_end, s_start,
+                              block_size=BLOCK, null_block=N_BLOCKS - 1,
+                              impl="pallas", interpret=False,
+                              k_scales=k_sc, v_scales=v_sc)
+
+    _assert_kernel_compiled(step, "paged_chunk_attention", params, pool, pool,
+                            scales if quantized else None,
+                            scales if quantized else None,
+                            s((BATCH, MAX_BLOCKS), jnp.int32),
+                            *[per_token] * 6)
