@@ -2,7 +2,8 @@
 
 Once the window has closed, a sample drawn from the seed of the requests
 the window's pipelines finished (the one with the most served tokens always
-among them, then others until some hundreds of served tokens are in) is run
+among them, and one that the adapter's view marks ``check_first`` where
+any is, then others until some hundreds of served tokens are in) is run
 once through the configuration's plain reference: each prompt exactly as it
 was submitted, followed by the tokens the system served. At each served
 position the reference's logits say how far the served token's logit lies
@@ -24,8 +25,11 @@ import numpy as np
 
 def sample(records: List[dict], seed: int, min_tokens: int, max_requests: int
            ) -> List[dict]:
-    """Finished window requests to compare: the longest, then others in an
-    order drawn from ``seed`` until ``min_tokens`` served tokens are in."""
+    """Finished window requests to compare: the longest and the first, in an
+    order drawn from ``seed``, of those marked ``check_first`` (served
+    through a path the cell exists to guard, such as blocks another replica
+    wrote) whatever the limits, then the others in that order until
+    ``min_tokens`` served tokens are in."""
     pool = [req for r in records if r["phase"] == "window"
             for req in r["requests"] if req["done"] and req["out_tokens"]
             and not req["truncated"]]
@@ -33,13 +37,17 @@ def sample(records: List[dict], seed: int, min_tokens: int, max_requests: int
         return []
     longest = max(range(len(pool)), key=lambda i: len(pool[i]["out_tokens"]))
     rng = np.random.default_rng([int(seed) & 0xFFFFFFFF, int(seed) >> 32, 5])
-    order = [longest] + [i for i in rng.permutation(len(pool)) if i != longest]
-    out, n = [], 0
-    for i in order:
+    rest = [i for i in rng.permutation(len(pool)) if i != longest]
+    marked = [] if pool[longest].get("check_first") else [
+        i for i in rest if pool[i].get("check_first")][:1]
+    out = [pool[i] for i in [longest] + marked]
+    n = sum(len(req["out_tokens"]) for req in out)
+    for i in rest:
         if n >= min_tokens or len(out) >= max_requests:
             break
-        out.append(pool[i])
-        n += len(pool[i]["out_tokens"])
+        if i not in marked:
+            out.append(pool[i])
+            n += len(pool[i]["out_tokens"])
     return out
 
 

@@ -114,7 +114,12 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, traced: bool,
     ref = spec.load_reference(cfg["reference"])
     dims = ref.Dims.from_config(cfg["model"])
     t0 = time.monotonic()
-    w = ref.make_weights(dims, seed, cfg["dtype"])
+    # an adapter whose system serves from weights split over several devices
+    # has them made there, so that no device holds a second, whole copy
+    place = getattr(ad, "weight_shardings", None)
+    shardings = None if place is None else place(cfg, dims, jax.eval_shape(
+        lambda: ref.make_weights(dims, seed, cfg["dtype"])))
+    w = ref.make_weights(dims, seed, cfg["dtype"], shardings=shardings)
     jax.block_until_ready(w)
     t_w = time.monotonic()
     engine = ad.build(cfg, w, dims)
@@ -176,7 +181,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, traced: bool,
     device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
               "count": len(devs), "memory_peak_bytes": peak}
     ctx = {"records": records, "counters": counters, "dims": dims, "config": cfg,
-           "plans": plans, "reduced": None, "peaks": None}
+           "reference": ref, "plans": plans, "reduced": None, "peaks": None}
     result = {"attempted": counts["pipelines"],
               "failed": counts["pipelines"] - counts["pipelines_finished"]
               + counts["truncated_requests"]}
@@ -205,6 +210,8 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, traced: bool,
     items = check.sample(records, seed, int(lim["min_tokens"]), int(lim["max_requests"]))
     got = check.gaps(ref, dims, w, items, controls=controls)
     counts["check_s"] = time.monotonic() - t_c
+    counts["check_requests"] = len(items)
+    counts["check_first_compared"] = sum(bool(r.get("check_first")) for r in items)
     ok = got["tokens"] >= 1 and got["gap_max"] <= float(lim["gap_limit"])
     result.update(correct=bool(ok), metrics=metrics, device=device, counts=counts)
     counts.update({k: v for k, v in got.items() if k.startswith("control_")})

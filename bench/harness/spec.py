@@ -4,7 +4,8 @@
 files behind those names are:
 
     bench/configs/<config>.json       model, engine settings, deployment
-    bench/references/<reference>.py   the plain reference a config names
+    bench/references/<reference>.py   the plain reference a config names,
+                                      with its architecture's work counts
     bench/adapters/<adapter>.py       the system under test a config names:
                                       how it is built, stepped and read
     bench/traffic/<traffic>.json      arrivals, mix, lengths, deadlines
@@ -31,6 +32,10 @@ from types import ModuleType
 from typing import Dict, List, Optional
 
 ROOT = Path(__file__).resolve().parents[2]
+
+
+REFERENCE_NEEDS = ("Dims", "make_weights", "segment_layout", "forward_logits",
+                   "step_flops", "kernel_work")
 
 
 class SpecError(ValueError):
@@ -176,9 +181,19 @@ def load_adapter(name: str, root: Path = ROOT) -> ModuleType:
 
 
 def load_reference(name: str, root: Path = ROOT) -> ModuleType:
-    """The plain reference a configuration names (``"reference"`` key)."""
-    return _load_module(root / "bench" / "references" / f"{name}.py",
-                        f"bench_reference_{name.replace('.', '_')}")
+    """The plain reference a configuration names (``"reference"`` key):
+    ``Dims.from_config``, ``make_weights``, ``segment_layout`` and
+    ``forward_logits`` for the weights and the check, and the
+    architecture's work counts that per-layer metrics read as
+    ``ctx["reference"]``: ``step_flops(dims, plan)`` (None for a step it
+    cannot count) and ``kernel_work(kernel, dims, config, plan)`` (FLOPs
+    and bytes, None where the step does not run the kernel)."""
+    mod = _load_module(root / "bench" / "references" / f"{name}.py",
+                       f"bench_reference_{name.replace('.', '_')}")
+    missing = [a for a in REFERENCE_NEEDS if not hasattr(mod, a)]
+    if missing:
+        raise SpecError(f"bench/references/{name}.py lacks {missing}")
+    return mod
 
 
 def load_peaks(device_kind: str, root: Path = ROOT) -> Dict[str, float]:

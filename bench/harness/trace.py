@@ -14,6 +14,9 @@ trace without a chip:
   module names such as ``jit__ragged_step_fn(...)``; a Pallas kernel is a
   ``tpu_custom_call`` operation inside its step program's module, since the
   program gives its kernels no names of their own);
+* per launch of a step program: its time on each device that ran it, so
+  that a program partitioned over several devices is one step, not one per
+  device;
 * idle gaps: each gap in the busy union, labelled with the host span that
   covers most of it.
 """
@@ -111,7 +114,7 @@ class Reduced:
     busy_s: float                    # mean over devices, inside the window
     in_flight_s: float
     busy_in_flight_s: float          # mean over devices
-    modules: Dict[str, List[float]]  # module name -> device seconds per run
+    modules: Dict[str, Dict[str, List[float]]]  # module -> device -> seconds per run
     ops: Dict[str, float]            # op label -> self seconds
     kernels: Dict[str, float]        # kernel name -> seconds
     kernel_calls: Dict[str, int]
@@ -171,7 +174,7 @@ def reduce(events: Sequence[Event], kernels: Dict[str, Dict[str, str]] = None,
     flight = _subtract([(lo, hi)], waits)
     in_flight = sum(b - a for a, b in flight)
     busy, busy_fl = [], []
-    modules: Dict[str, List[float]] = {}
+    modules: Dict[str, Dict[str, List[float]]] = {}
     ops: Dict[str, float] = {}
     kern = {k: 0.0 for k in kernels}
     kcalls = {k: 0 for k in kernels}
@@ -188,7 +191,8 @@ def reduce(events: Sequence[Event], kernels: Dict[str, Dict[str, str]] = None,
         mods = sorted(_line(events, dev, "XLA Modules"), key=lambda e: e.start)
         for e in mods:
             if e.start >= lo and e.end <= hi:
-                modules.setdefault(e.name, []).append((e.end - e.start) / 1e9)
+                modules.setdefault(e.name, {}).setdefault(dev, []).append(
+                    (e.end - e.start) / 1e9)
         starts = [m.start for m in mods]
         for e, own in zip(dev_ops, _self_times(dev_ops, lo, hi)):
             i = bisect.bisect_right(starts, e.start) - 1
@@ -232,10 +236,24 @@ def _label(host: Sequence[Event], a: int, b: int) -> str:
 
 def module_times(red: Reduced, fragments) -> List[float]:
     """Device seconds of every run of the modules whose names hold one of
-    ``fragments``."""
+    ``fragments``, on every device."""
+    return [t for runs in launches(red, fragments) for t in runs]
+
+
+def launches(red: Reduced, fragments) -> List[List[float]]:
+    """Device seconds of each launch of the modules whose names hold one of
+    ``fragments``, one entry per device that ran it. A module's name carries
+    its program's fingerprint, and each device runs one program's launches
+    in order, so the k-th run of a module on every device that runs it is
+    its k-th launch. The traced window opens and closes with the device
+    idle, so no launch is cut at its edges."""
     frags = list(fragments)
-    return [t for name, ts in red.modules.items()
-            if any(f in name for f in frags) for t in ts]
+    out: List[List[float]] = []
+    for name, per_dev in red.modules.items():
+        if any(f in name for f in frags):
+            n = max(len(ts) for ts in per_dev.values())
+            out.extend([ts[k] for ts in per_dev.values() if k < len(ts)] for k in range(n))
+    return out
 
 
 def breakdown(red: Reduced, n: int = 10) -> Dict[str, list]:

@@ -1,8 +1,9 @@
 """``paged_chunk_attention`` (the packed fused step's attention kernel):
 the least time its traced calls could take at the chip's peaks, over the
-time they took. Work from each traced step's tokens, cache slots and
-attention spans (``harness/work.py``): every row's attended slots read
-once, dead table columns and padding not counted."""
+time they took (``work.roofline_pct``). The work of each traced step that
+runs it comes from the configuration's own reference (``kernel_work``):
+every row's attended slots read once, dead table columns and padding not
+counted."""
 from bench.harness import work
 
 LAYER = "kernels (kernels/decode_attention.py)"
@@ -12,15 +13,4 @@ KERNEL = "paged_chunk_attention"
 
 
 def read(ctx):
-    red = ctx["reduced"]
-    if red is None:
-        return None
-    spent = red.kernels.get(KERNEL, 0.0)
-    plans = [p for p in ctx["plans"] if p["kind"] == "ragged"]
-    if spent <= 0 or not plans:
-        return None
-    d, kv, act = ctx["dims"], ctx["config"]["kv_bytes"], ctx["config"]["act_bytes"]
-    least = sum(work.min_time(*work.chunk_kernel_work(
-        d, kv, act, p["row_of"], p["slots"], p["p_end"], p["s_start"]), ctx["peaks"])
-        for p in plans)
-    return 100.0 * least / spent
+    return work.roofline_pct(ctx, KERNEL)

@@ -1,6 +1,7 @@
-"""Mean device time of one dispatched step program (the packed fused step
-and the decode step, matched by the XLA module names the configuration
-lists), from the traced window."""
+"""Mean device time of one dispatched step program on one chip (the packed
+fused step and the decode step, matched by the XLA module names the
+configuration lists), from the traced window. A step partitioned over
+several devices counts its time on each of them once."""
 from bench.harness import trace
 
 LAYER = "step programs (serving/engine.py)"

@@ -25,6 +25,10 @@ configuration's bfloat16: ``"int8"`` quantizes every weight per output
 channel and every matmul input per row to int8 (symmetric absmax);
 ``"fp8"`` rounds them to float8 e4m3 (4 significant bits, per-tensor scale
 to the format's largest value, 448).
+
+The work counts that the per-layer metrics read for this architecture live
+here too (``step_flops``, ``kernel_work``): model FLOPs of a served step
+and each paged attention kernel's FLOPs and bytes, from the step's plan.
 """
 from __future__ import annotations
 
@@ -34,6 +38,8 @@ from dataclasses import dataclass
 from typing import Dict, Optional, Sequence, Tuple
 
 import numpy as np
+
+from bench.harness import work
 
 Q_BLOCK = 256        # query rows per attention block
 SEQ_BUCKET = 512     # sequences pad to a multiple of this: few compiled shapes
@@ -83,6 +89,79 @@ class Dims:
 
 
 # ---------------------------------------------------------------------------
+# work counts
+# ---------------------------------------------------------------------------
+
+
+def linear_flops_per_token(d) -> float:
+    """Projections and MLP of every layer, for one computed token."""
+    qd, kvd = d.heads * d.head_dim, d.kv_heads * d.head_dim
+    per_layer = 2 * (d.d_model * qd + 2 * d.d_model * kvd + qd * d.d_model
+                     + 3 * d.d_model * d.d_ff)
+    return float(per_layer * d.layers)
+
+
+def logit_flops(d) -> float:
+    """The output head for one sampled token."""
+    return 2.0 * d.d_model * d.vocab
+
+
+def attn_flops(d, ctx) -> float:
+    """Scores and value sum of one layer for tokens with contexts ``ctx``."""
+    return 4.0 * d.heads * d.head_dim * float(np.sum(ctx))
+
+
+def chunk_kernel_work(d, kv_bytes: int, act_bytes: int, row_of, slots, p_end,
+                      s_start) -> Tuple[float, float]:
+    """(FLOPs, bytes) of ``paged_chunk_attention`` over all layers for one
+    packed step: each row's attended slots read once, every query read and
+    every output written once."""
+    flops = attn_flops(d, work.token_contexts(slots, p_end, s_start))
+    kv_slots = work.attended_slots(row_of, slots, p_end, s_start)
+    kv = kv_slots * d.kv_heads * d.head_dim * 2 * kv_bytes
+    qo = len(slots) * d.heads * d.head_dim * 2 * act_bytes
+    return flops * d.layers, float(kv + qo) * d.layers
+
+
+def decode_kernel_work(d, kv_bytes: int, act_bytes: int, ctx) -> Tuple[float, float]:
+    """(FLOPs, bytes) of ``paged_decode_attention`` over all layers for one
+    decode step whose rows attend ``ctx`` slots each."""
+    ctx = np.asarray(ctx, np.int64)
+    kv = float(ctx.sum()) * d.kv_heads * d.head_dim * 2 * kv_bytes
+    qo = len(ctx) * d.heads * d.head_dim * 2 * act_bytes
+    return attn_flops(d, ctx) * d.layers, (kv + qo) * d.layers
+
+
+def step_flops(d, plan: Dict) -> Optional[float]:
+    """Model FLOPs of one step: every computed token through every layer,
+    its attention over its own context, and the head where a token is
+    sampled. None for a kind of step this count does not know."""
+    if plan["kind"] == "ragged":
+        ctx = work.token_contexts(plan["slots"], plan["p_end"], plan["s_start"])
+    elif plan["kind"] == "decode":
+        ctx = np.asarray(plan["ctx"])
+    else:
+        return None
+    return (len(ctx) * linear_flops_per_token(d) + attn_flops(d, ctx) * d.layers
+            + plan["sampled"] * logit_flops(d))
+
+
+def kernel_work(kernel: str, d, config: Dict, plan: Dict) -> Optional[Tuple[float, float]]:
+    """(FLOPs, bytes) of ``kernel`` in one step, or None where the step does
+    not run it. A kernel this architecture does not count is an error."""
+    if kernel == "paged_chunk_attention":
+        if plan["kind"] != "ragged":
+            return None
+        return chunk_kernel_work(d, config["kv_bytes"], config["act_bytes"], plan["row_of"],
+                                 plan["slots"], plan["p_end"], plan["s_start"])
+    if kernel == "paged_decode_attention":
+        if plan["kind"] != "decode":
+            return None
+        return decode_kernel_work(d, config["kv_bytes"], config["act_bytes"], plan["ctx"])
+    raise KeyError(f"no work count for kernel {kernel!r}")
+
+
+# ---------------------------------------------------------------------------
 # seeded weights
 # ---------------------------------------------------------------------------
 
@@ -95,13 +174,18 @@ def _key(seed: int):
     return jax.random.fold_in(k, (seed >> 31) & 0x7FFFFFFF)
 
 
-def make_weights(dims: Dims, seed: int, dtype: str = "bfloat16") -> Dict:
+def make_weights(dims: Dims, seed: int, dtype: str = "bfloat16",
+                 shardings: Optional[Dict] = None) -> Dict:
     """Random weights from ``seed``, made on the device in one jitted call
-    in the served dtype. Rows of the embedding (and head) past the published
-    vocabulary are zero: they exist only because tables pad to 128 rows."""
+    in the served dtype, placed as ``shardings`` says (by weight name) where
+    given. Rows of the embedding (and head) past the published vocabulary
+    are zero: they exist only because tables pad to 128 rows."""
     import jax
 
-    return jax.jit(functools.partial(_make_weights, dims, dtype))(_key(seed))
+    make = functools.partial(_make_weights, dims, dtype)
+    if shardings is not None:
+        return jax.jit(make, out_shardings=shardings)(_key(seed))
+    return jax.jit(make)(_key(seed))
 
 
 def _make_weights(dims: Dims, dtype: str, key) -> Dict:

@@ -11,7 +11,7 @@ import time
 
 import pytest
 
-from bench.harness import runner, spec
+from bench.harness import check, runner, spec
 
 SEED = 2**31 + 9
 
@@ -70,3 +70,30 @@ def test_planted_fault_is_not_correct(tiny_cell, tmp_path, monkeypatch, fault):
     r = run(tiny_cell, tmp_path)
     assert r["correct"] is False
     assert r["checks"]["gap_max"]["value"] > r["checks"]["gap_max"]["limit"]
+
+
+def _records(lengths, marked=()):
+    reqs = [{"out_tokens": [1] * n, "done": True, "truncated": False,
+             "check_first": i in marked, "id": i} for i, n in enumerate(lengths)]
+    return [{"phase": "window", "requests": reqs}]
+
+
+@pytest.mark.parametrize("seed", [3, 2**31 + 9, 2**33 + 1])
+def test_sample_takes_the_longest_then_the_seeds_order(seed):
+    recs = _records([5, 40, 7, 9, 11, 3])
+    got = [r["id"] for r in check.sample(recs, seed, 60, 6)]
+    again = [r["id"] for r in check.sample(recs, seed, 60, 6)]
+    assert got[0] == 1 and got == again
+    assert sum(len(r["out_tokens"]) for r in check.sample(recs, seed, 60, 6)) >= 60
+
+
+@pytest.mark.parametrize("seed", [3, 2**31 + 9, 2**33 + 1])
+def test_sample_always_holds_a_marked_request(seed):
+    """A request marked ``check_first`` is compared even where the longest
+    alone fills the sample's tokens or its count."""
+    recs = _records([5, 40, 7, 9, 11, 3], marked=(4, 5))
+    for min_tokens, max_requests in ((30, 6), (400, 2), (400, 6)):
+        ids = [r["id"] for r in check.sample(recs, seed, min_tokens, max_requests)]
+        assert ids[0] == 1 and ids[1] in (4, 5) and len(set(ids)) == len(ids)
+    unmarked = _records([5, 40, 7, 9, 11, 3])
+    assert [r["id"] for r in check.sample(unmarked, seed, 30, 6)] == [1]

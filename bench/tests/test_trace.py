@@ -1,7 +1,9 @@
 """The reduction from a profiler trace to busy time, idle share, step
-program and kernel time, and idle gaps by what the host was doing."""
+program and kernel time, and idle gaps by what the host was doing; on one
+device and on four, where one step runs on several devices."""
 import json
 
+import numpy as np
 import pytest
 
 from bench.harness import trace
@@ -84,3 +86,77 @@ def test_a_trace_without_window_or_device_is_refused():
         trace.reduce([e for e in synthetic() if e.name != "bench:traced"])
     with pytest.raises(ValueError):
         trace.reduce([e for e in synthetic() if e.plane == H])
+
+
+# ---------------------------------------------------------------------------
+# four devices: two replicas whose steps interleave
+# ---------------------------------------------------------------------------
+
+DEVS = [f"/device:TPU:{i}" for i in range(4)]
+
+
+def four_device_events(layout: str):
+    """Two replicas, each with its own compiled ragged and decode programs
+    (their own fingerprints), stepping in turn. ``"mesh"``: every step runs
+    on all four devices, as the program's group runs it (the replicas share
+    one pool sharded over the mesh); ``"pairs"``: replica 0 on devices 0-1,
+    replica 1 on devices 2-3, their steps overlapping in time. Each device's
+    run of a step is 100 us, plus 10 us on devices 1 and 3; times in us."""
+    steps = [(0, "jit__ragged_step_fn(11)"), (1, "jit__ragged_step_fn(22)"),
+             (0, "jit__decode_paged_fn(33)"), (1, "jit__decode_paged_fn(44)"),
+             (0, "jit__decode_paged_fn(33)"), (1, "jit__decode_paged_fn(44)")]
+    ev = [Event(H, "python", "bench:traced", 0, 2000)]
+    t = 100
+    for replica, mod in steps:
+        devs = DEVS if layout == "mesh" else DEVS[2 * replica: 2 * replica + 2]
+        for i, dev in enumerate(devs):
+            end = t + 100 + (10 if dev in (DEVS[1], DEVS[3]) else 0)
+            ev += [Event(dev, "XLA Modules", mod, t, end),
+                   Event(dev, "XLA Ops", "%fusion.3 = bf16[8] fusion(...)", t, end)]
+        t += 200 if layout == "mesh" else 60 * (replica + 1)
+    return [Event(e.plane, e.line, e.name, e.start * 1000, e.end * 1000) for e in ev]
+
+
+def group_plans():
+    ragged = {"kind": "ragged", "row_of": np.array([0, 0]), "slots": np.array([0, 1]),
+              "p_end": np.array([0, 0]), "s_start": np.array([0, 0]), "sampled": 1}
+    decode = {"kind": "decode", "ctx": np.array([3, 5]), "sampled": 2}
+    return [ragged, ragged, decode, decode, decode, decode]
+
+
+@pytest.mark.parametrize("layout", ["mesh", "pairs"])
+def test_four_devices_one_launch_per_step(layout):
+    red = trace.reduce(four_device_events(layout))
+    assert red.devices == DEVS
+    frags = ["ragged_step", "decode_paged"]
+    runs = trace.launches(red, frags)
+    per = 4 if layout == "mesh" else 2
+    assert len(runs) == 6 and all(len(r) == per for r in runs)
+    assert sorted(map(sorted, runs))[0] == [pytest.approx(100e-6)] * (per // 2) + [
+        pytest.approx(110e-6)] * (per // 2)
+    assert len(trace.module_times(red, frags)) == 6 * per
+
+
+@pytest.mark.parametrize("layout", ["mesh", "pairs"])
+def test_step_ms_and_step_mfu_are_per_chip_on_four_devices(layout):
+    from bench.harness import spec
+
+    from .conftest import ROOT
+
+    red = trace.reduce(four_device_events(layout))
+    cfg = json.loads((ROOT / "bench" / "configs" / "qwen2.5-3b-dp2tp2.json").read_text())
+    ref = spec.load_reference(cfg["reference"], ROOT)
+    dims = ref.Dims.from_config(cfg["model"])
+    peaks = spec.load_peaks("TPU v5 lite", ROOT)
+    plans = group_plans()
+    ctx = {"reduced": red, "plans": plans, "dims": dims, "config": cfg, "reference": ref,
+           "peaks": peaks}
+    per = 4 if layout == "mesh" else 2
+    # a step's time on one chip: 100 us on half its devices, 110 on the others
+    assert spec.load_reader("step_ms", ROOT).read(ctx) == pytest.approx(0.105)
+    flops = sum(ref.step_flops(dims, p) for p in plans)
+    chip_s = 6 * per * 105e-6
+    mfu = spec.load_reader("step_mfu", ROOT).read(ctx)
+    assert mfu == pytest.approx(100 * flops / (chip_s * peaks["bf16_flops_per_s"]))
+    # a plan without its launch, or a launch without its plan, reads nothing
+    assert spec.load_reader("step_mfu", ROOT).read(dict(ctx, plans=plans[:-1])) is None
