@@ -12,7 +12,12 @@ Validity (cache slots filled so far) comes from a per-row length input.
 serving engine (vLLM-style PagedAttention): the KV pool is a global array of
 fixed-size blocks, and a scalar-prefetched per-sequence block table drives
 the BlockSpec index_map, so each grid cell DMAs exactly the physical block
-the logical position maps to — no contiguous cache materialization.
+the logical position maps to — no contiguous cache materialization. Its grid
+cell is one (row, table column) pair, and only the columns up to the row's
+last live block (``(length - 1) // bs``) do work: past it the index_maps
+repeat the block already in VMEM (no DMA) and the cell skips the math. A row
+of length 0 is empty: it attends nothing, runs no cell's math and returns
+zeros.
 ``ref_paged_decode_attention`` is the jnp gather oracle the kernel (and the
 engine's XLA decode path) are checked against.
 
@@ -29,9 +34,11 @@ token, and a tile reads only the blocks up to its last attended slot.
 Both kernels tolerate RAW block tables: pad entries (-1) are masked inside
 the kernel (index_maps clamp them to block 0 purely so the DMA has a legal
 source; the scores of those slots are forced to -inf). Callers no longer
-need to pre-clamp or reroute tables before handing them to the kernels.
-Fully-masked query rows (a packed pad token, ``row_of < 0``) produce finite
-garbage — never NaN — and must be discarded by the caller.
+need to pre-clamp or reroute tables before handing them to the kernels, and
+a table padded with a scratch block instead is never read past a row's last
+live block. Fully-masked query rows (a packed pad token, ``row_of < 0``, or
+an empty decode row) produce finite output — never NaN — and must be
+discarded by the caller.
 """
 from __future__ import annotations
 
@@ -205,9 +212,19 @@ def _split_rest(rest, quantized):
     return (None, None) + tuple(rest)
 
 
-def _paged_decode_kernel(tab_ref, len_ref, q_ref, k_ref, v_ref, *rest,
-                         block_size: int, nkv: int, kvh: int, scale: float,
-                         quantized: bool = False):
+def decode_live_cells(lengths, block_size: int, max_blocks: int) -> int:
+    """The grid cells of ``paged_decode_attention`` that run the math for
+    per-row ``lengths`` (0 = empty row): each row's columns up to its last
+    live one, ``(length - 1) // block_size`` at most ``max_blocks - 1``
+    (-1 for an empty row), as the kernel's wrapper derives them on device."""
+    lengths = np.asarray(lengths, np.int64)
+    last = np.minimum((lengths - 1) // block_size, max_blocks - 1)
+    return int((last + 1).sum())
+
+
+def _paged_decode_kernel(tab_ref, len_ref, last_ref, q_ref, k_ref, v_ref,
+                         *rest, block_size: int, nkv: int, kvh: int,
+                         scale: float, quantized: bool = False):
     ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = _split_rest(rest, quantized)
     b = pl.program_id(0)
     j = pl.program_id(1)
@@ -216,15 +233,20 @@ def _paged_decode_kernel(tab_ref, len_ref, q_ref, k_ref, v_ref, *rest,
     def _init():
         _init_scratch(acc_ref, m_ref, l_ref)
 
-    # logical position of this block's slots = j*bs + offset; valid when below
-    # the sequence length AND backed by a real page — a raw -1 table entry is
-    # masked here in the kernel (the index_map clamps it to block 0 only so
-    # the DMA has a legal source), so callers may pass unclamped tables even
-    # when interior entries are holes
-    kpos = j * block_size + jax.lax.broadcasted_iota(jnp.int32, (1, block_size), 1)
-    valid = (tab_ref[b, j] >= 0) & (kpos < len_ref[b])
-    _online_softmax_heads(q_ref, k_ref, v_ref, ks_ref, vs_ref, acc_ref,
-                          m_ref, l_ref, valid, kvh=kvh, scale=scale)
+    # past the row's last live block (and on an empty row) the index_maps
+    # repeat the block already in VMEM, so there is no DMA; skip the math
+    @pl.when(j <= last_ref[b])
+    def _update():
+        # logical position of this block's slots = j*bs + offset; valid when
+        # below the sequence length AND backed by a real page — a raw -1
+        # table entry is masked here in the kernel (the index_map clamps it
+        # to block 0 only so the DMA has a legal source), so callers may pass
+        # unclamped tables even when interior entries are holes
+        kpos = j * block_size + jax.lax.broadcasted_iota(
+            jnp.int32, (1, block_size), 1)
+        valid = (tab_ref[b, j] >= 0) & (kpos < len_ref[b])
+        _online_softmax_heads(q_ref, k_ref, v_ref, ks_ref, vs_ref, acc_ref,
+                              m_ref, l_ref, valid, kvh=kvh, scale=scale)
 
     @pl.when(j == nkv - 1)
     def _done():
@@ -255,14 +277,21 @@ def paged_decode_attention(
 
     q: (B, H, hd); k/v_pool: (n_blocks, bs, KVH, hd) — ONE layer group's
     global pool; block_tables: (B, max_blocks) int32 (-1 = unallocated);
-    lengths: (B,) valid tokens per sequence. Returns (B, H, hd).
+    lengths: (B,) valid tokens per sequence, 0 for an empty row. Returns
+    (B, H, hd).
 
-    Grid (B, max_blocks): the scalar-prefetched block table feeds the K/V
-    BlockSpec index_map, so each cell DMAs the one physical block (all KV
-    heads) its logical block index maps to. The table may be RAW: -1
-    entries (pad or interior holes) are masked to -inf inside the kernel,
-    independent of the length check. Lengths must be >= 1 per row (a
-    fully-masked row would softmax over nothing).
+    Grid (B, max_blocks), one cell per (row, table column). The scalar-
+    prefetched block table feeds the K/V BlockSpec index_map, so cell
+    (b, j) DMAs the one physical block (all KV heads) that column j of row
+    b maps to, for j up to the row's last live column ``(length - 1) //
+    bs`` (also scalar-prefetched). Past it the index_map repeats that last
+    block, which is already in VMEM, so no DMA is issued, and the cell
+    skips the online-softmax update: a table padded with a scratch block
+    is never read there. A row of length 0 runs no cell's math (its
+    index_map stays on column 0) and returns zeros, which the caller
+    discards. The table may also be RAW: -1 entries (pad or interior
+    holes) are masked to -inf inside the kernel, independent of the length
+    check.
 
     ``k_scale``/``v_scale`` ((n_blocks, KVH) float32, both or neither) mark
     an int8-quantized pool: each cell DMAs its block at half the HBM bytes
@@ -279,15 +308,21 @@ def paged_decode_attention(
     qf = q.reshape(B, KVH, G, hd)
     tables = jnp.asarray(block_tables, jnp.int32)
     lens = jnp.asarray(lengths, jnp.int32).reshape(B)
+    # each row's last live column, -1 on an empty row (``decode_live_cells``)
+    last = jnp.minimum((lens - 1) // bs, mb - 1)
 
-    def q_map(b, j, tab_ref, len_ref):
+    def q_map(b, j, *_):
         return (b, 0, 0, 0)
 
-    def kv_map(b, j, tab_ref, len_ref):
-        return (jnp.maximum(tab_ref[b, j], 0), 0, 0, 0)
+    def block_of(b, j, tab_ref, len_ref, last_ref):
+        col = jnp.maximum(jnp.minimum(j, last_ref[b]), 0)
+        return jnp.maximum(tab_ref[b, col], 0)
 
-    def sc_map(b, j, tab_ref, len_ref):
-        return (jnp.maximum(tab_ref[b, j], 0), 0, 0)
+    def kv_map(b, j, *refs):
+        return (block_of(b, j, *refs), 0, 0, 0)
+
+    def sc_map(b, j, *refs):
+        return (block_of(b, j, *refs), 0, 0)
 
     kernel = functools.partial(
         _paged_decode_kernel, block_size=bs, nkv=mb, kvh=KVH, scale=scale,
@@ -298,13 +333,13 @@ def paged_decode_attention(
         pl.BlockSpec((1, bs, KVH, hd), kv_map),
         pl.BlockSpec((1, bs, KVH, hd), kv_map),
     ]
-    operands = [tables, lens, qf, k_pool, v_pool]
+    operands = [tables, lens, last, qf, k_pool, v_pool]
     if quantized:
         specs, scales = _scale_specs(k_scale, v_scale, nb, KVH, sc_map)
         in_specs += specs
         operands += scales
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B, mb),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, KVH, G, hd), q_map),

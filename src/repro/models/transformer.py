@@ -679,9 +679,12 @@ def apply_layer_decode_paged(cfg, kind, lp, x, k_slice, v_slice, tables, pos,
     ``kernels.paged_decode_attention`` — no contiguous view gather. x:
     (B, 1, D); k/v_slice: (n_blocks, bs, KVH, hd); tables: (B, mb); pos:
     (B,) absolute position of the new token (rows must be table-backed at
-    ``pos`` — the plan allocates before it decodes). ``k_sc``/``v_sc``
-    ((n_blocks, KVH) float32) mark an int8 pool slice: the token's K/V
-    quantizes at scatter time and the kernel dequantizes in VMEM."""
+    ``pos`` — the plan allocates before it decodes). A row the plan left
+    empty has ``pos`` 0 (a live row has a prompt before its new token), so
+    it attends nothing: the kernel gets length 0 for it and runs no cell.
+    ``k_sc``/``v_sc`` ((n_blocks, KVH) float32) mark an int8 pool slice:
+    the token's K/V quantizes at scatter time and the kernel dequantizes in
+    VMEM."""
     from repro.kernels.decode_attention import paged_decode_attention
     from repro.models.layers import apply_rope
     from repro.serving.paged_cache import _quantized_scatter
@@ -722,8 +725,9 @@ def apply_layer_decode_paged(cfg, kind, lp, x, k_slice, v_slice, tables, pos,
             k_slice, k_sc = scatter_q(k_slice, k_sc, k[:, 0])
             v_slice, v_sc = scatter_q(v_slice, v_sc, v[:, 0])
     with jax.named_scope("attn"):
+        lengths = jnp.where(pos > 0, pos + 1, 0)
         a_out = paged_decode_attention(
-            q[:, 0], k_slice, v_slice, tables, pos + 1,
+            q[:, 0], k_slice, v_slice, tables, lengths,
             k_scale=k_sc, v_scale=v_sc, interpret=interpret
         )
         x = x + (a_out.reshape(B, 1, cfg.num_heads * cfg.head_dim)

@@ -44,7 +44,7 @@ from typing import Any, Callable, Deque, Dict, List, Optional, Tuple
 import numpy as np
 
 from repro.core.streaming import streaming_chunk_policy
-from repro.kernels.decode_attention import chunk_tile_count
+from repro.kernels.decode_attention import chunk_tile_count, decode_live_cells
 
 
 @dataclass(frozen=True, eq=False)
@@ -441,6 +441,8 @@ class ControlPlane:
             tokens[r.slot, 0] = self._decode_token(r, prev_slots)
             starts[r.slot] = r.pos
             temps[r.slot] = r.temperature
+        eng.decode_live_cells += decode_live_cells(
+            [r.pos + 1 for r in active], eng.block_size, eng.max_blocks)
         emit: List[Tuple[Any, int, bool]] = []
         for r in active:
             r.pos += 1

@@ -411,6 +411,10 @@ class GenerationEngine:
         # query tiles the ragged steps' chunk attention launches
         # (fused_valid_tokens / chunk_tiles is tokens per tile)
         self.chunk_tiles = 0
+        # cells of one layer's decode-kernel grid that ran the math, summed
+        # over the decode steps (over steps x max_batch x max_blocks: the
+        # live share of the grid)
+        self.decode_live_cells = 0
         self.host_store = host_store
         self.pipeline = bool(pipeline) and self.interleave
         self.flusher = flusher if flusher is not None else PriorityFlusher()
@@ -605,6 +609,7 @@ class GenerationEngine:
             s["fused_slot_tokens"] = self.fused_slot_tokens
             s["fused_valid_tokens"] = self.fused_valid_tokens
             s["chunk_tiles"] = self.chunk_tiles
+            s["decode_live_cells"] = self.decode_live_cells
             s["padded_token_fraction"] = (
                 1.0 - self.fused_valid_tokens / self.fused_slot_tokens
                 if self.fused_slot_tokens else 0.0

@@ -282,6 +282,34 @@ def test_chunk_tiles_counts_the_kernels_query_tiles():
     assert eng.stats()["chunk_tiles"] == want > len(plans)
 
 
+def test_decode_live_cells_counts_the_decode_kernels_live_cells():
+    """``stats()["decode_live_cells"]`` sums, over the decode-only steps,
+    the cells of ``paged_decode_attention``'s grid that run the math: each
+    live row's columns up to the block holding its new token."""
+    cfg = _cfg()
+    eng = GenerationEngine(cfg, max_batch=4, max_seq=96, n_blocks=32,
+                           block_size=8, prefill_chunk_size=16,
+                           token_budget=20, kernel="pallas")
+    plans = []
+    assemble = eng.control._assemble_decode
+
+    def spy(*args):
+        plans.append(assemble(*args))
+        return plans[-1]
+
+    eng.control._assemble_decode = spy
+    rng = np.random.default_rng(5)
+    for n, new in ((30, 12), (5, 20), (12, 6)):
+        eng.submit(rng.integers(0, 90, size=n), max_new=new)
+    eng.run_until_done(max_steps=200)
+    assert plans
+    want = sum(int(p.starts[row]) // eng.block_size + 1
+               for p in plans for _req, row, _fin in p.emit_rows)
+    assert eng.stats()["decode_live_cells"] == want
+    # fewer cells than the grid: empty rows and dead columns ran nothing
+    assert len(plans) < want < len(plans) * eng.max_batch * eng.max_blocks
+
+
 # ------------------------------------------------------------ int8 KV pools
 def _greedy_agreement(reqs_a, reqs_b) -> float:
     match = total = 0

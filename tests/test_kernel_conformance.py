@@ -170,6 +170,85 @@ def test_paged_decode_raw_table_with_holes():
     _assert_decode_matches(case, interpret=True)
 
 
+# ------------------------------------- decode: the engine's table convention
+def _engine_decode_case(rng, *, pool, B=6, kvh=2, g=2, hd=32, bs=8, mb=9):
+    """A decode batch laid out as the serving engine hands it over: tables
+    padded with a null block (not -1), empty rows at length 0, live rows of
+    1 to 8 blocks, some with a spare allocated block past their length.
+    The null block and every block past a row's length hold NaN (NaN
+    scales on an int8 pool), so a cell that read one would poison its row.
+    Returns (q, k, v, k_scale, v_scale) as the kernel takes them, the same
+    values with those blocks zeroed for the oracle (a bf16 pool in float32,
+    so the oracle's products stay exact), the tables and the lengths."""
+    lengths = rng.integers(1, 8 * bs + 1, size=B)
+    order = rng.permutation(B)
+    lengths[order[:2]] = 0                     # empty rows
+    lengths[order[2]] = 8 * bs                 # eight whole blocks
+    lengths[order[3]] = rng.integers(1, bs + 1)  # one block
+    n_blocks = B * mb + 1
+    null = n_blocks - 1
+    tables = np.full((B, mb), null, np.int32)
+    free = list(rng.permutation(null))
+    dead = [null]
+    for b, ln in enumerate(lengths):
+        need = -(-ln // bs)
+        tables[b, :need] = [free.pop() for _ in range(need)]
+        if ln and need < mb and rng.random() < 0.5:
+            tables[b, need] = free.pop()
+            dead.append(tables[b, need])
+    # values a bf16 pool holds exactly
+    kp, vp = (np.array(x.astype(jnp.bfloat16), np.float32)
+              for x in _make_pool(rng, n_blocks, bs, kvh, hd))
+    q = jnp.asarray(rng.standard_normal((B, kvh * g, hd)).astype(np.float32))
+    if pool == "int8":
+        kq, ks, vq, vs = _quantize_pool(kp, vp)
+        ks_z, vs_z = ks.copy(), vs.copy()
+        ks[dead], vs[dead] = np.nan, np.nan
+        ks_z[dead], vs_z[dead] = 0.0, 0.0
+        got = (kq, vq, ks, vs)
+        zeroed = (kq, vq, ks_z, vs_z)
+    else:
+        kz, vz = kp.copy(), vp.copy()
+        kp[dead], vp[dead] = np.nan, np.nan
+        kz[dead], vz[dead] = 0.0, 0.0
+        got = (kp, vp, None, None)
+        zeroed = (kz, vz, None, None)
+
+    def dev(k, v, ks, vs, dt=None):
+        return (q, jnp.asarray(k, dt), jnp.asarray(v, dt),
+                None if ks is None else jnp.asarray(ks),
+                None if vs is None else jnp.asarray(vs))
+
+    dt = jnp.bfloat16 if pool == "bfloat16" else None
+    return (dev(*got, dt=dt), dev(*zeroed), jnp.asarray(tables),
+            jnp.asarray(lengths))
+
+
+def _assert_dead_cells_unread(rng, pool, interpret, **shape):
+    (q, k, v, ks, vs), (_, kz, vz, ksz, vsz), tables, lengths = (
+        _engine_decode_case(rng, pool=pool, **shape))
+    got = np.asarray(paged_decode_attention(
+        q, k, v, tables, lengths, k_scale=ks, v_scale=vs,
+        interpret=interpret))
+    with jax.default_matmul_precision("highest"):
+        want = np.asarray(ref_paged_decode_attention(
+            q, kz, vz, tables, lengths, k_scale=ksz, v_scale=vsz))
+    live = np.asarray(lengths) > 0
+    assert np.all(np.isfinite(got)), "a dead cell was read"
+    np.testing.assert_array_equal(got[~live], 0.0)   # empty rows: zeros
+    np.testing.assert_allclose(got[live], want[live], **TOL)
+
+
+@pytest.mark.parametrize("seed", range(3))
+@pytest.mark.parametrize("pool", ["bfloat16", "int8"])
+def test_paged_decode_never_reads_dead_cells(pool, seed):
+    """Null-block padding, empty rows and blocks past a row's length are
+    never read: NaN there leaves every output finite, and live rows match
+    the oracle on the same pool with those blocks zeroed."""
+    _assert_dead_cells_unread(np.random.default_rng(800 + seed), pool,
+                              interpret=True)
+
+
 # -------------------------------------------------- chunk: seeded shape sweep
 @pytest.mark.parametrize("seed", range(4))
 def test_paged_chunk_random_mixes(seed):
@@ -449,6 +528,13 @@ def test_paged_decode_compiled(seed):
     case = _decode_case(rng, B=4, kvh=2, g=2, hd=64, bs=16, mb=4,
                         n_blocks=32)
     _assert_decode_matches(case, interpret=False)
+
+
+@pytest.mark.skipif(not ON_TPU, reason="compiled Mosaic kernels need a TPU")
+@pytest.mark.parametrize("seed", range(2))
+def test_paged_decode_never_reads_dead_cells_compiled(seed):
+    _assert_dead_cells_unread(np.random.default_rng(850 + seed), "bfloat16",
+                              interpret=False, hd=128, bs=16)
 
 
 @pytest.mark.skipif(not ON_TPU, reason="compiled Mosaic kernels need a TPU")
