@@ -165,11 +165,11 @@ def one_chip(serve, jax, seed: int) -> None:
     if eng.backend != "paged" or eng.kernel != "pallas":
         raise AssertionError(f"backend={eng.backend} kernel={eng.kernel}: "
                              "want the paged engine on the Pallas kernels")
-    if not (eng.ragged and eng.pipeline and eng.kv.prefix_sharing):
-        raise AssertionError("want the ragged pipelined step with prefix sharing")
+    if not (eng.pipeline and eng.kv.prefix_sharing):
+        raise AssertionError("want the pipelined step with prefix sharing")
     n_variants = eng.warmup_step_variants()
     print(f"[chip_smoke] engine: backend={eng.backend} kernel={eng.kernel} "
-          f"ragged={eng.ragged} pipeline={eng.pipeline} "
+          f"pipeline={eng.pipeline} "
           f"n_blocks={eng.kv.pool.n_blocks} max_seq={max_seq} "
           f"pool={fit['pool_bytes'] / 2**30:.3f} GiB "
           f"weights={fit['weights_bytes'] / 2**30:.3f} GiB")

@@ -1,7 +1,7 @@
 """Declarative contract audit over the engine's traced step programs.
 
 ``GenerationEngine.step_program(which)`` exposes every device program the
-serving loop can dispatch — fused ragged/padded mixed-batch steps, the
+serving loop can dispatch — the packed mixed-batch step, the
 Pallas and gather-oracle decode programs, and the bare pool
 gather/scatter roundtrip. This module traces each one and checks a
 :class:`StepContract` against it:
@@ -255,7 +255,7 @@ def cache_sentinel(engine, warm: bool = True) -> Finding:
     """Compile-cache sentinel: after warmup, the ragged step jit must hold
     exactly the warmed pack-aligned bucket variants — growth means some
     path is minting off-bucket packed lengths (silent mid-serve compiles)."""
-    if engine.backend != "paged" or not engine.interleave or not engine.ragged:
+    if engine.backend != "paged":
         return Finding("fused_ragged", "cache-sentinel", True,
                        "n/a (no ragged variants on this engine)")
     buckets = engine.warmup_step_variants() if warm else None
@@ -347,9 +347,8 @@ def default_contracts(engine) -> List[StepContract]:
     on_mesh = engine.pool_layout is not None
     ar = None if on_mesh else 0
     int8k = engine.kv_dtype == "int8" and engine.kernel == "pallas"
-    fused = "fused_ragged" if engine.ragged else "fused_padded"
     contracts = [
-        StepContract(fused, max_all_reduce=ar,
+        StepContract("fused_ragged", max_all_reduce=ar,
                      require_int8_kernel_path=int8k),
         StepContract("decode", max_all_reduce=ar,
                      require_int8_kernel_path=int8k),

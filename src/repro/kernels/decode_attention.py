@@ -453,9 +453,9 @@ def _paged_chunk_kernel(tab_ref, row_ref, last_ref, n_ref, slot_ref, pend_ref,
     # index_maps repeat the previous block, so there is no DMA; skip the math
     @pl.when((i < n_ref[0]) & (j <= last_ref[i]))
     def _update():
-        # the segmented-prompt span mask (models.transformer.apply_layer_prefix)
-        # per query row: the shared prelude (slot < p_end) plus the token's
-        # own document segment up to itself (s_start <= slot <= own slot);
+        # the segmented-prompt span mask per query row (serving.segments): a
+        # token attends the shared prelude (slot < p_end) plus its own
+        # document segment up to itself (s_start <= slot <= own slot);
         # flat prompts and decode rows pass p_end = s_start = 0, plain causal.
         # A query row with no token has slot -1 and masks everything; raw -1
         # table entries mask the whole block.
@@ -483,10 +483,10 @@ def paged_chunk_attention(
     q: (T, H, hd) — the flat fused batch, decode rows and prefill chunks
     packed back to back (no chunk-width padding); k/v_pool: (n_blocks, bs,
     KVH, hd) — ONE layer group's global pool, already holding the packed
-    chunk's own K/V (the stack writes before attention, exactly like the
-    chunked-prefill path); block_tables: (B, max_blocks) int32, RAW (-1
-    entries masked in-kernel); row_of: (T,) int32 owning batch row per token
-    (-1 = packed pad token, output garbage-but-finite, caller discards);
+    chunk's own K/V (the stack writes before attention); block_tables:
+    (B, max_blocks) int32, RAW (-1 entries masked in-kernel); row_of: (T,)
+    int32 owning batch row per token (-1 = packed pad token, output
+    garbage-but-finite, caller discards);
     slots: (T,) absolute cache slot of each token; p_end / s_start: (T,)
     segmented-prompt attention spans (zeros = plain causal over slots).
     Returns (T, H, hd).

@@ -376,20 +376,6 @@ def decode_attention(q, k_cache, v_cache, valid_mask, scale: Optional[float] = N
     return _grouped_out(probs, v_cache, q.dtype)  # (B,1,H,hd)
 
 
-def chunk_decode_attention(q, k_cache, v_cache, valid_mask, scale: Optional[float] = None):
-    """Chunked-prefill attention: C query tokens against a KV cache that
-    already contains both the cached prefix and the chunk's own entries.
-
-    q: (B, C, H, hd); k/v_cache: (B, Sc, KVH, hd); valid_mask: (B, C, Sc)
-    (per-query causal validity over absolute cache slots)."""
-    hd = q.shape[-1]
-    scale = scale if scale is not None else 1.0 / math.sqrt(hd)
-    scores = _grouped_scores(q, k_cache, scale)  # (B,KVH,G,C,Sc)
-    scores = jnp.where(valid_mask[:, None, None, :, :], scores, NEG_INF)
-    probs = jax.nn.softmax(scores, axis=-1)
-    return _grouped_out(probs, v_cache, q.dtype)  # (B,C,H,hd)
-
-
 def cache_validity(attn_type: str, cache_len: int, pos, chunk: int = 0):
     """Which cache slots a decode query may attend, given absolute position
     ``pos`` of the new token. Ring caches (SWA) are fully valid once wrapped;

@@ -205,43 +205,6 @@ def prefill(cfg, params, batch):
     return logits[:, -1], caches
 
 
-def prefill_chunk(cfg, params, caches, tokens, pos, positions=None,
-                  seg_prefix_end=None, seg_start=None):
-    """Chunked prefill: run C prompt tokens (cache slots ``pos .. pos+C-1``)
-    against the serve cache, writing their K/V entries in place. ``pos`` is a
-    scalar, or a (B,) vector of per-row start positions — the engine's fused
-    interleaved step batches decode rows and prefill chunks from different
-    requests, each at its own cursor. Long retrieved contexts stream through
-    in fixed-size chunks instead of being bucketed (and silently truncated)
-    to a power of two. Returns (logits (B, C, V), new caches).
-
-    Segmented prompts pass ``positions`` (B,C) rope positions decoupled from
-    cache slots plus ``seg_prefix_end``/``seg_start`` (B,C) attention spans
-    (document segments attend the prelude + themselves only), making
-    per-document KV order-independent; defaults reproduce plain causal
-    prefill. Supported for full-attention GQA stacks
-    (``paged_cache_supported``); other mixers keep the whole-prompt prefill
-    path."""
-    x = embed_tokens(params["embed"], tokens)
-    if (cfg.is_encoder_decoder or not cfg.use_rope) and not cfg.attention_free:
-        C = x.shape[1]
-        sin_at = lambda p_: _sinusoidal_at(p_, cfg.d_model)
-        if jnp.ndim(pos) == 0:
-            pe = jax.vmap(sin_at)(pos + jnp.arange(C))[None]
-        else:
-            pe = jax.vmap(lambda p0: jax.vmap(sin_at)(p0 + jnp.arange(C)))(pos)
-        x = x + pe.astype(x.dtype)
-    x, new_caches = tfm.run_stack_prefix(
-        cfg, params["blocks"], x, caches, pos, positions, seg_prefix_end, seg_start
-    )
-    x = tfm.apply_norm(cfg, params["final_norm"], x)
-    logits = unembed(params["embed"], params.get("lm_head"), x, cfg.tie_embeddings)
-    if cfg.padded_vocab != cfg.vocab_size:  # mask pad-vocab logits (as forward)
-        pad_bias = jnp.where(jnp.arange(cfg.padded_vocab) < cfg.vocab_size, 0.0, -1e30)
-        logits = logits + pad_bias.astype(logits.dtype)
-    return logits, new_caches
-
-
 def prefill_packed(cfg, params, k_pool, v_pool, tables, tokens, row_of, slots,
                    positions, p_end, s_start, *, block_size, null_block,
                    impl="reference", interpret=True, k_scales=None,

@@ -241,17 +241,13 @@ def _dequantize_kv(x, s, dtype):
 
 def _cache_update(c, new, pos):
     """Write new entries starting at pos % Sc. c: (B, Sc, ...); new: (B, C, ...).
-    pos may be a scalar (dry-run serve_step / single-sequence chunked prefill)
-    or (B,) per-row starts (continuous batching; the fused interleaved batch
-    mixes decode rows with C-token prefill chunks at per-row positions)."""
+    pos may be a scalar (dry-run serve_step) or (B,) per-row positions of
+    one new token each (continuous batching)."""
     Sc = c.shape[1]
     new = new.astype(c.dtype)
     if jnp.ndim(pos) == 0:
         return jax.lax.dynamic_update_slice_in_dim(c, new, pos % Sc, 1)
-    if new.shape[1] == 1:
-        return c.at[jnp.arange(c.shape[0]), pos % Sc].set(new[:, 0])
-    idx = (pos[:, None] + jnp.arange(new.shape[1])) % Sc
-    return c.at[jnp.arange(c.shape[0])[:, None], idx].set(new)
+    return c.at[jnp.arange(c.shape[0]), pos % Sc].set(new[:, 0])
 
 
 def apply_layer_decode(cfg, kind, lp, x, cache, pos, enc_out_unused=None):
@@ -334,88 +330,6 @@ def apply_layer_decode(cfg, kind, lp, x, cache, pos, enc_out_unused=None):
         valid = jnp.ones((B, Sc), bool)
         c_out = attn.decode_attention(q, cache["ck"], cache["cv"], valid)
         x = x + c_out.reshape(B, 1, -1) @ lp["cross_attn"]["wo"]
-
-    xn = apply_norm(cfg, lp["norm2"], x)
-    if "moe" in lp:
-        ffn_out, _ = moe_mod.apply_moe(lp["moe"], xn, cfg)
-    else:
-        ffn_out = apply_mlp(lp["mlp"], xn, cfg.act)
-    return x + ffn_out, new_cache
-
-
-# ---------------------------------------------------------------------------
-# per-layer apply: chunked prefill mode (C tokens against a cached prefix)
-# ---------------------------------------------------------------------------
-
-
-def apply_layer_prefix(cfg, kind, lp, x, cache, pos, positions=None,
-                       seg_prefix_end=None, seg_start=None):
-    """Chunked prefill: x (B,C,D) of prompt tokens at cache slots
-    ``pos .. pos+C-1`` attends the cached prefix plus itself (causal). The
-    chunk's K/V entries are written into the cache before attention, so the
-    returned cache is ready for the next chunk or for decode. ``pos`` is a
-    scalar (all rows aligned) or (B,) per-row starts — the fused interleaved
-    batch runs every row at its own cursor, decode rows included (C-padded
-    chunks of one valid token).
-
-    Segmented prompts (retrieval-aware prefix caching) decouple a token's
-    RoPE position and attention span from its cache slot: ``positions``
-    (B,C) overrides the rope positions (document segments restart at the
-    prelude length so their K/V is order-independent), and the attention mask
-    becomes ``slot < seg_prefix_end[t]  OR  seg_start[t] <= slot <= slot(t)``
-    — document tokens attend the prelude plus their own segment only. The
-    defaults (positions == slots, seg bounds 0) reproduce plain causal
-    prefill bit-for-bit.
-
-    Full-attention GQA stacks only (the paged serving path); other mixers keep
-    the bucketed whole-prompt prefill."""
-    from repro.models.layers import apply_rope
-
-    B, C, _ = x.shape
-    at = kind["attn_type"]
-    if at != ATTN_FULL or kind["cross"]:
-        raise NotImplementedError(
-            "chunked prefix prefill supports full-attention GQA stacks only"
-        )
-    xn = apply_norm(cfg, lp["norm1"], x)
-    if jnp.ndim(pos) == 0:
-        slots = jnp.broadcast_to(
-            (pos + jnp.arange(C)).astype(jnp.int32)[None], (B, C)
-        )
-    else:
-        slots = (pos[:, None] + jnp.arange(C)[None, :]).astype(jnp.int32)
-    if positions is None:
-        positions = slots
-    q, k, v = attn.qkv_project(lp["attn"], xn, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim)
-    if cfg.use_rope:
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
-    Sc = cache["k"].shape[1]
-    new_cache = dict(cache)
-    if cfg.kv_cache_quant:
-        qk, sk = _quantize_kv(k)
-        qv, sv = _quantize_kv(v)
-        kc = _cache_update(cache["k"], qk, pos)
-        vc = _cache_update(cache["v"], qv, pos)
-        ksc = _cache_update(cache["k_scale"], sk, pos)
-        vsc = _cache_update(cache["v_scale"], sv, pos)
-        k_read = _dequantize_kv(kc, ksc, q.dtype)
-        v_read = _dequantize_kv(vc, vsc, q.dtype)
-        new_cache.update(k_scale=ksc, v_scale=vsc)
-    else:
-        kc = _cache_update(cache["k"], k, pos)
-        vc = _cache_update(cache["v"], v, pos)
-        k_read, v_read = kc, vc
-    s = jnp.arange(Sc)[None, None, :]
-    if seg_prefix_end is None:
-        valid = s <= slots[:, :, None]  # (B,C,Sc) plain causal over slots
-    else:
-        valid = (s < seg_prefix_end[:, :, None]) | (
-            (s >= seg_start[:, :, None]) & (s <= slots[:, :, None])
-        )
-    a_out = attn.chunk_decode_attention(q, k_read, v_read, valid)
-    x = x + a_out.reshape(B, C, cfg.num_heads * cfg.head_dim) @ lp["attn"]["wo"]
-    new_cache.update(k=kc, v=vc)
 
     xn = apply_norm(cfg, lp["norm2"], x)
     if "moe" in lp:
@@ -517,32 +431,6 @@ def _segment_size(G: int) -> int:
     return best
 
 
-def run_stack_prefix(cfg, blocks, x, caches, pos, positions=None,
-                     seg_prefix_end=None, seg_start=None):
-    """Scan the layer stack in chunked-prefill mode: x (B,C,D) written into
-    (and attending) the serve cache at absolute start slot ``pos`` — scalar,
-    or (B,) per-row starts for the fused interleaved batch (the chunk must
-    fit inside the cache, no ring wrap). ``positions``/``seg_prefix_end``/
-    ``seg_start`` (all (B,C), optional) carry the segmented-prompt rope
-    positions and attention spans; see ``apply_layer_prefix``."""
-    p = period(cfg)
-    kinds = [layer_kind(cfg, i) for i in range(p)]
-
-    def body(x, slices):
-        block_slice, cache_slice = slices
-        new_caches = []
-        for i in range(p):
-            x, nc = apply_layer_prefix(
-                cfg, kinds[i], block_slice[i], x, cache_slice[i], pos,
-                positions, seg_prefix_end, seg_start,
-            )
-            new_caches.append(nc)
-        return x, tuple(new_caches)
-
-    x, new_caches = jax.lax.scan(body, x, (blocks, caches))
-    return x, new_caches
-
-
 def apply_layer_paged(cfg, kind, lp, x, k_slice, v_slice, tables, row_of,
                       slots, positions, p_end, s_start, *, block_size,
                       null_block, k_sc=None, v_sc=None, impl="reference",
@@ -555,17 +443,21 @@ def apply_layer_paged(cfg, kind, lp, x, k_slice, v_slice, tables, row_of,
     x: (1, T, D); k/v_slice: (n_blocks, bs, KVH, hd) one layer group's pool;
     tables: (B, mb) int32 RAW block tables (-1 holes allowed); row_of/slots/
     positions/p_end/s_start: (T,) per-token owning row, absolute cache slot,
-    rope position and segment-attention span (see ``apply_layer_prefix`` —
-    the mask ``slot < p_end  OR  s_start <= slot <= own slot`` is identical,
-    applied per packed token instead of per (row, chunk-col)).
+    rope position and segment-attention span. Segmented prompts
+    (retrieval-aware prefix caching, ``serving.segments``) decouple a
+    token's rope position and span from its cache slot: document segments
+    restart at the prelude length so their K/V is order-independent, and a
+    token attends ``slot < p_end  OR  s_start <= slot <= own slot`` — the
+    prelude plus its own segment. Flat prompts and decode rows pass
+    positions == slots and zero spans: plain causal.
 
     The chunk's K/V entries are scattered into the pool BEFORE attention
-    (``write_paged_packed``), mirroring the chunked-prefill path, so each
-    token's own entry — and every earlier packed token of the same row — is
-    visible to its query. ``impl`` selects the attention read: "pallas"
-    streams blocks through ``kernels.paged_chunk_attention``; "reference"
-    gathers per-token views and runs the masked-softmax oracle (the numerics
-    contract, and the path that keeps working under shard_map meshes).
+    (``write_paged_packed``), so each token's own entry — and every earlier
+    packed token of the same row — is visible to its query. ``impl``
+    selects the attention read: "pallas" streams blocks through
+    ``kernels.paged_chunk_attention``; "reference" gathers per-token views
+    and runs the masked-softmax oracle (the numerics contract, and the path
+    that keeps working under shard_map meshes).
 
     ``k_sc``/``v_sc`` ((n_blocks, KVH) float32, both or neither) mark an
     int8-quantized pool slice: writes quantize at scatter time

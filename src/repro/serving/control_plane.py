@@ -53,27 +53,24 @@ class StepPlan:
     the runner uploads them; nothing here holds device state."""
 
     plan_id: int
-    kind: str                # "ragged" (packed mixed batch) | "fused"
-    #                          (padded mixed batch) | "decode"
-    tokens: np.ndarray       # ragged: (T,) flat packed tokens; fused: (B, C)
-    #                          chunk tokens; decode: (B, 1)
+    kind: str                # "ragged" (packed mixed batch) | "decode"
+    tokens: np.ndarray       # ragged: (T,) flat packed tokens; decode: (B, 1)
     starts: np.ndarray       # (B,) int32 per-row cursor / decode position
     temps: np.ndarray        # (B,) float32 sampling temperatures
     tables: np.ndarray       # (B, view_blocks | max_blocks) int32 block
     #                          tables — RAW (-1 holes) for ragged plans,
-    #                          scratch-filled for fused/decode
+    #                          scratch-filled for decode
     # rows whose decode token must be substituted with the PREVIOUS plan's
     # device-resident sampled token (-1 = feed the host-provided token)
     prev_slots: np.ndarray   # (B,) int32
     # rows whose sampled token is delivered: (request, row, finishing)
     emit_rows: Tuple[Tuple[Any, int, bool], ...]
     n_tokens: int            # valid tokens this step (per-token calibration)
-    n_valid: Optional[np.ndarray] = None     # mixed only: (B,) valid counts
-    positions: Optional[np.ndarray] = None   # mixed only: rope positions —
-    #                                          (T,) ragged, (B, C) fused
-    p_end: Optional[np.ndarray] = None       # mixed only: attention span ends
-    s_start: Optional[np.ndarray] = None     # mixed only: span starts
-    # ragged layout only: the packed batch's row-offset arrays
+    # ragged only: (B,) valid counts, then the packed batch's (T,) arrays
+    n_valid: Optional[np.ndarray] = None
+    positions: Optional[np.ndarray] = None   # (T,) rope positions
+    p_end: Optional[np.ndarray] = None       # (T,) attention span ends
+    s_start: Optional[np.ndarray] = None     # (T,) span starts
     row_of: Optional[np.ndarray] = None      # (T,) owning batch row, -1 = pad
     slots: Optional[np.ndarray] = None       # (T,) absolute cache slot
     decode_idx: Optional[np.ndarray] = None  # (B,) flat index of the row's
@@ -225,10 +222,8 @@ class ControlPlane:
 
             with span("engine.plan.assemble"):
                 if prefill_rows:
-                    assemble = (self._assemble_ragged if eng.ragged
-                                else self._assemble_fused)
-                    plan = assemble(plan_id, active, prefill_rows,
-                                    decode_rows, prev_slots)
+                    plan = self._assemble_ragged(plan_id, active, prefill_rows,
+                                                 decode_rows, prev_slots)
                 else:
                     plan = self._assemble_decode(plan_id, active, prev_slots)
 
@@ -243,10 +238,7 @@ class ControlPlane:
     def _grants(self, prefill_rows, decode_rows) -> Dict[int, int]:
         """Token-budget grants: decode rows reserve one token each; the
         remaining budget goes to mid-prefill rows in policy order (always
-        at least one token, so prefill can never fully starve). Identical
-        for the ragged and padded layouts — the plan SEQUENCE (grants,
-        bookkeeping, emissions) is layout-independent by construction,
-        which is what makes ragged-vs-padded token parity testable."""
+        at least one token, so prefill can never fully starve)."""
         eng = self.eng
         budget = max(eng.token_budget - len(decode_rows), 1)
         grants: Dict[int, int] = {}
@@ -261,7 +253,7 @@ class ControlPlane:
     def _mixed_bookkeeping(self, plan_id, prefill_rows, decode_rows, grants):
         """Build-time bookkeeping for one mixed step (the state the NEXT
         plan reads): cursor/position advances, kv lengths, prefix
-        publication, and the emit list. Shared by both batch layouts."""
+        publication, and the emit list."""
         eng = self.eng
         emit: List[Tuple[Any, int, bool]] = []
         n_tok = 0
@@ -289,55 +281,6 @@ class ControlPlane:
                 r.pos = r.prefill_cap
                 emit.append(self._mark_sampled(r, plan_id))
         return emit, n_tok
-
-    def _assemble_fused(self, plan_id, active, prefill_rows, decode_rows,
-                        prev_slots) -> StepPlan:
-        """Padded mixed batch (legacy layout): every row a chunk-width slab
-        at its own cursor, decode rows one valid token in C columns. Kept as
-        the layout oracle for the ragged packing (``ragged=False``)."""
-        eng = self.eng
-        grants = self._grants(prefill_rows, decode_rows)
-
-        # compose the fused batch: every row a chunk at its own cursor
-        B, C = eng.max_batch, eng.prefill_chunk_size
-        tokens = np.zeros((B, C), np.int32)
-        starts = np.zeros((B,), np.int32)
-        n_valid = np.zeros((B,), np.int32)
-        temps = np.zeros((B,), np.float32)
-        positions = np.zeros((B, C), np.int32)
-        p_end = np.zeros((B, C), np.int32)
-        s_start = np.zeros((B, C), np.int32)
-        tables = np.full((B, eng._view_blocks), eng._null_block, np.int32)
-        rows = eng.kv.pool.table_array([r.req_id for r in active],
-                                       eng._view_blocks)
-        for i, r in enumerate(active):
-            backed = rows[i] >= 0
-            tables[r.slot, backed] = rows[i][backed]
-            temps[r.slot] = r.temperature
-            if r.prefilling:
-                c = grants.get(r.req_id, 0)
-                tokens[r.slot, :c] = r.prompt[r.prefill_pos : r.prefill_pos + c]
-                starts[r.slot] = r.prefill_pos
-                n_valid[r.slot] = c
-                pp, pe, ss = eng._seg_arrays(r, r.prefill_pos, c, C)
-                positions[r.slot], p_end[r.slot], s_start[r.slot] = pp[0], pe[0], ss[0]
-            else:
-                tokens[r.slot, 0] = self._decode_token(r, prev_slots)
-                starts[r.slot] = r.pos
-                n_valid[r.slot] = 1
-                positions[r.slot, 0] = r.pos  # decoded tokens: position == slot
-
-        emit, n_tok = self._mixed_bookkeeping(
-            plan_id, prefill_rows, decode_rows, grants
-        )
-        eng.fused_slot_tokens += B * C
-        eng.fused_valid_tokens += n_tok
-        return StepPlan(
-            plan_id=plan_id, kind="fused", tokens=tokens, starts=starts,
-            temps=temps, tables=tables, prev_slots=prev_slots,
-            emit_rows=tuple(emit), n_tokens=n_tok, n_valid=n_valid,
-            positions=positions, p_end=p_end, s_start=s_start,
-        )
 
     def _assemble_ragged(self, plan_id, active, prefill_rows, decode_rows,
                          prev_slots) -> StepPlan:

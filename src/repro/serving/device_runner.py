@@ -3,9 +3,9 @@
 The runner is the device half of the control-plane split. Its contract:
 
 * **Same programs, same numerics.** It runs the engine's OWN compiled step
-  programs (``_fused_step_jit`` / ``_decode_paged_jit``) unchanged, so the
-  logits — and therefore greedy tokens — are bit-identical to the
-  sequential oracle. Around them sit two tiny extra jits: a prev-token
+  programs (``_ragged_step_jit`` / ``_decode_dispatch_jit``) unchanged, so
+  the logits — and therefore greedy tokens — do not depend on when plans
+  materialize. Around them sit two tiny extra jits: a prev-token
   substitution (decode rows feed the previous plan's sampled token straight
   from device memory, no host roundtrip) and the sampler.
 
@@ -69,8 +69,6 @@ def _is_ready(arr) -> bool:
 _INPUTS = {
     "ragged": ("tokens", "prev_slots", "decode_idx", "tables", "row_of", "slots",
                "positions", "p_end", "s_start", "last_idx", "temps"),
-    "fused": ("tokens", "prev_slots", "tables", "starts", "n_valid", "positions",
-              "p_end", "s_start", "temps"),
     "decode": ("tokens", "prev_slots", "tables", "starts", "temps"),
 }
 
@@ -161,11 +159,6 @@ class DeviceRunner:
             logits, *out = eng._ragged_step_jit(
                 *state, toks_in, dev["row_of"], dev["slots"], dev["positions"],
                 dev["p_end"], dev["s_start"], dev["last_idx"])
-        elif kind == "fused":
-            toks_in = self._subst_jit(dev["tokens"], prev, dev["prev_slots"])
-            logits, *out = eng._fused_step_jit(
-                *state, toks_in, dev["starts"], dev["n_valid"],
-                dev["positions"], dev["p_end"], dev["s_start"])
         else:
             toks_in = self._subst_jit(dev["tokens"], prev, dev["prev_slots"])
             logits, *out = eng._decode_dispatch_jit(*state, toks_in,

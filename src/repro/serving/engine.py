@@ -25,19 +25,17 @@ Two cache backends:
   land here automatically.
 
 Scheduling (paged backend): Sarathi-style batched chunked prefill with
-decode interleaving (``interleave=True``, the default). Prefill no longer
-completes inside admission — each request carries a persistent prefill
-cursor (``Request.prefill_pos``) and every ``step()`` assembles one mixed
-batch: a decode token for every decode-phase slot plus prefill chunks from
-one or more mid-prefill slots, bounded by a per-step ``token_budget``, then
-runs a single fused forward (`models.prefill_chunk` with per-row
-start/n_valid — decode rows are chunks of one valid token). Decode slots
-therefore emit a token on every step even while a long retrieved context is
-prefilling (bounded TPOT under bursty RAG load), and TTFT stretches only by
-chunk quantization. A `core.scheduler.QueuePolicy` (FIFO or EDF-slack)
-orders both admission and the per-step prefill-budget grants.
-``interleave=False`` keeps the sequential blocking-prefill loop as the
-parity oracle; greedy decode is token-exact across the two modes.
+decode interleaving. Prefill never completes inside admission — each
+request carries a persistent prefill cursor (``Request.prefill_pos``) and
+every ``step()`` plans one batch. A mixed batch packs a decode token for
+every decode-phase slot plus prefill chunks from one or more mid-prefill
+slots, bounded by a per-step ``token_budget``, into one flat token buffer
+and runs it as a single forward (`models.prefill_packed`; a decode row is
+one packed token). A batch with no prefill runs the decode step. Decode
+slots therefore emit a token on every step even while a long retrieved
+context is prefilling (bounded TPOT under bursty RAG load), and TTFT
+stretches only by chunk quantization. A `core.scheduler.QueuePolicy` (FIFO
+or EDF-slack) orders both admission and the per-step prefill-budget grants.
 
 Preemption (paged backend): pool exhaustion picks the youngest active
 request and applies the engine's ``preempt`` strategy —
@@ -58,7 +56,7 @@ document-block sharing. Eviction-aware admission closes the loop: the
 ``resident_first`` scheduler policy prefers requests whose doc blocks are
 HBM- or host-resident (``core.scheduler``).
 
-Runtime / control-plane split (interleaved paged mode): the engine is a thin
+Runtime / control-plane split (paged backend): the engine is a thin
 orchestrator over three layers — a host-side ``ControlPlane`` that builds an
 immutable ``StepPlan`` per step (``serving.control_plane``), a
 ``DeviceRunner`` that executes plans through the engine's own jitted step
@@ -98,7 +96,6 @@ from repro.models import (
     init_cache,
     init_params,
     paged_cache_supported,
-    prefill_chunk,
     prefill_packed,
 )
 from repro.serving.control_plane import ControlPlane, CopyEngine
@@ -110,10 +107,7 @@ from repro.serving.paged_cache import (
     _quantized_scatter,
     gather_paged_batch,
     gather_paged_batch_dq,
-    write_paged_chunk,
     write_paged_chunk_batch,
-    write_paged_chunk_batch_q,
-    write_paged_chunk_q,
 )
 from repro.serving.sampler import sample_tokens
 from repro.serving.segments import SegmentedPrompt, build_layout
@@ -256,7 +250,6 @@ class GenerationEngine:
         prefill_chunk_size: int = 64,
         n_blocks: Optional[int] = None,
         prefix_sharing: bool = True,
-        interleave: bool = True,
         token_budget: Optional[int] = None,
         scheduler: Any = "fifo",
         max_finished: int = 10_000,
@@ -271,7 +264,6 @@ class GenerationEngine:
         host_bw_bytes_s: float = 8e9,
         copy_budget: int = 4,
         kernel: Optional[str] = None,
-        ragged: bool = True,
         pack_align: int = 4,
         kv_dtype: Optional[str] = None,
         sanitize: bool = False,
@@ -279,7 +271,7 @@ class GenerationEngine:
         """``mesh`` / ``pool_layout`` shard the paged backend over a device
         mesh: params become TP-resident (Megatron layout, embed/lm_head
         replicated), the KV pool arrays shard over the model axis by KV head,
-        and the three step programs are pjit-compiled with pinned pool
+        and the step programs are pjit-compiled with pinned pool
         shardings — every block-table gather and chunk scatter is local per
         shard, so the only communication is the post-attention/MLP output
         reductions (``audit_collectives`` asserts this). With neither given
@@ -298,25 +290,21 @@ class GenerationEngine:
         ``preempt="swap"``/``"cost"`` provision one automatically
         (device-pool-sized) when neither is given.
 
-        ``pipeline`` (interleaved paged mode only) defers sampled-token
+        ``pipeline`` (paged backend only) defers sampled-token
         materialization one step so plan N+1 is built while step N runs;
         ``pipeline=False`` is the eager sync oracle, greedy-token-identical.
 
         ``kernel`` selects the paged hot-path attention implementation:
         ``"pallas"`` runs ``kernels.paged_decode_attention`` for decode
-        plans and ``kernels.paged_chunk_attention`` for the ragged fused
+        plans and ``kernels.paged_chunk_attention`` for the packed mixed
         step; ``"reference"`` is the jnp gather oracle. ``None`` lets the
         platform pick: compiled Pallas on a TPU, the reference elsewhere
         (where Pallas would only run in its interpreter). A mesh needs an
-        explicit ``"reference"``. ``ragged``
-        (interleaved mode) packs the fused mixed batch into one flat token
-        buffer (decode rows cost one slot, not a chunk-width row; tables go
-        to the device RAW, unbacked pages masked in the kernel);
-        ``ragged=False`` keeps the legacy chunk-width padded layout as the
-        packing oracle. ``pack_align`` rounds the flat buffer length to
-        bound jit retraces. ``kernel="pallas"`` is single-device only (the
-        Pallas calls don't partition under shard_map meshes yet) and
-        requires the ragged layout for fused steps.
+        explicit ``"reference"``: the Pallas calls don't partition under
+        shard_map meshes yet. A mixed step packs its rows into one flat
+        token buffer (a decode row costs one slot; tables go to the device
+        RAW, unbacked pages masked in the attention); ``pack_align`` rounds
+        the buffer length to bound jit retraces.
         ``flusher`` shares one PriorityFlusher across engines (DP groups);
         ``host_bw_bytes_s`` calibrates the cost model's swap estimate;
         ``copy_budget`` bounds per-step async copy draining.
@@ -346,7 +334,6 @@ class GenerationEngine:
         if backend == "paged" and not paged_cache_supported(cfg):
             backend = "dense"  # arch outside the paged contract: parity oracle path
         self.backend = backend
-        self.interleave = interleave and backend == "paged"
         self.scheduler: QueuePolicy = make_policy(scheduler)
         # eviction-aware admission: residency-aware policies score a waiting
         # request by how much of its prompt is HBM-/host-resident. Never
@@ -394,18 +381,11 @@ class GenerationEngine:
                 "kernels do not partition under shard_map meshes yet; pass "
                 "kernel='reference' on a mesh"
             )
-        if kernel == "pallas" and not ragged:
-            raise ValueError(
-                "kernel='pallas' requires the ragged fused layout: the "
-                "chunk kernel consumes the packed token buffer"
-            )
         self.kernel = kernel
-        self.ragged = bool(ragged)
         self.pack_align = max(int(pack_align), 1)
         self._interpret = default_interpret()
-        # fused-batch occupancy: device slots dispatched vs slots holding a
-        # real token — 1 - valid/slot is the padding-FLOP fraction the
-        # ragged layout exists to remove
+        # mixed-step occupancy: packed slots dispatched vs slots holding a
+        # real token — 1 - valid/slot is the tail-alignment padding share
         self.fused_slot_tokens = 0
         self.fused_valid_tokens = 0
         # query tiles the ragged steps' chunk attention launches
@@ -416,7 +396,7 @@ class GenerationEngine:
         # live share of the grid)
         self.decode_live_cells = 0
         self.host_store = host_store
-        self.pipeline = bool(pipeline) and self.interleave
+        self.pipeline = bool(pipeline) and self.backend == "paged"
         self.flusher = flusher if flusher is not None else PriorityFlusher()
         self.host_bw_bytes_s = host_bw_bytes_s
         self.copy_budget = copy_budget
@@ -442,8 +422,8 @@ class GenerationEngine:
             # budget for one step's valid tokens (decode rows + prefill chunks);
             # default leaves room for every decode slot plus one full chunk
             self.token_budget = token_budget or (max_batch + prefill_chunk_size)
-            # the prefill view carries slack blocks so a padded chunk write
-            # never runs past the end of the gathered cache
+            # mixed-step tables: max_blocks plus one chunk of slack blocks
+            # (the width the packed step programs are compiled for)
             self._view_blocks = self.max_blocks + -(-prefill_chunk_size // block_size)
             if n_blocks is None:
                 # full provisioning: every slot can reach max_seq (+ slack), +1 scratch
@@ -505,13 +485,9 @@ class GenerationEngine:
                 # empty pytree leaves under the tuple, no sharding to pin
                 out_s = (rep, pool_s, pool_s, None, None)
                 self._decode_paged_jit = jax.jit(self._decode_paged_fn, out_shardings=out_s)
-                self._prefill_chunk_jit = jax.jit(self._prefill_chunk_fn, out_shardings=out_s)
-                self._fused_step_jit = jax.jit(self._fused_step_fn, out_shardings=out_s)
                 self._ragged_step_jit = jax.jit(self._ragged_step_fn, out_shardings=out_s)
             else:
                 self._decode_paged_jit = jax.jit(self._decode_paged_fn)
-                self._prefill_chunk_jit = jax.jit(self._prefill_chunk_fn)
-                self._fused_step_jit = jax.jit(self._fused_step_fn)
                 self._ragged_step_jit = jax.jit(self._ragged_step_fn)
             if kernel == "pallas":
                 # pallas decode replaces the gather-oracle program wholesale;
@@ -577,7 +553,6 @@ class GenerationEngine:
     def stats(self) -> Dict[str, Any]:
         s: Dict[str, Any] = {
             "backend": self.backend,
-            "interleave": self.interleave,
             "pipeline": self.pipeline,
             "steps": self.steps,
             "tokens_out": self.tokens_out,
@@ -605,7 +580,6 @@ class GenerationEngine:
             s["preempt"] = self.preempt
             s["kv_dtype"] = self.kv_dtype or str(jnp.dtype(self.cfg.dtype))
             s["kernel"] = self.kernel
-            s["ragged"] = self.ragged
             s["fused_slot_tokens"] = self.fused_slot_tokens
             s["fused_valid_tokens"] = self.fused_valid_tokens
             s["chunk_tiles"] = self.chunk_tiles
@@ -628,19 +602,17 @@ class GenerationEngine:
         return s
 
     def warmup_step_variants(self) -> int:
-        """Pre-compile every packed fused-step variant off the serving clock.
+        """Pre-compile every packed mixed-step variant off the serving clock.
 
-        The ragged layout trades the padded slab's single static shape for
-        one jit variant per tail-aligned packed length; a production engine
-        captures those buckets at startup rather than paying compiles
-        mid-serve (the padding-FLOP win only shows once the variants are
-        warm). The packed length is bounded by the token budget — decode
-        rows displace prefill grants one for one (with the +1 floor grant)
-        — and by the padded slab, so the sweep is small. Each dummy call
-        packs only masked pad tokens (``row_of = -1``) and its pool outputs
-        are discarded, leaving engine state untouched. Returns the number
-        of variants compiled."""
-        if self.backend != "paged" or not self.interleave or not self.ragged:
+        The packed step compiles one jit variant per tail-aligned packed
+        length; a production engine captures those buckets at startup
+        rather than paying compiles mid-serve. The packed length is bounded
+        by the token budget — decode rows displace prefill grants one for
+        one (with the +1 floor grant) — and by ``max_batch`` chunks, so the
+        sweep is small. Each dummy call packs only masked pad tokens
+        (``row_of = -1``) and its pool outputs are discarded, leaving engine
+        state untouched. Returns the number of variants compiled."""
+        if self.backend != "paged":
             return 0
         B, C = self.max_batch, self.prefill_chunk_size
         budget = self.token_budget or B * C
@@ -669,10 +641,8 @@ class GenerationEngine:
         step programs, the single entry point behind every static audit
         (collective census, jaxpr contract audit, cache sentinel):
 
-        * ``"fused_ragged"`` — the packed mixed-batch step (production path
-          when ``ragged=True``), against a representative packed buffer.
-        * ``"fused_padded"`` — the padded-slab fused step (the ragged
-          path's shape-stable fallback and oracle).
+        * ``"fused_ragged"`` — the packed mixed-batch step, against a
+          representative packed buffer.
         * ``"decode"`` — the live decode dispatch: the Pallas paged-decode
           program when ``kernel="pallas"``, else the gather oracle.
         * ``"decode_ref"`` — always the gather-oracle decode jit (stays
@@ -686,10 +656,7 @@ class GenerationEngine:
         without touching engine state."""
         B, C = self.max_batch, self.prefill_chunk_size
         k, v = self.kv.k, self.kv.v
-        tokens = jnp.zeros((B, C), jnp.int32)
         starts = jnp.zeros((B,), jnp.int32)
-        n_valid = jnp.ones((B,), jnp.int32)
-        seg = jnp.zeros((B, C), jnp.int32)
         if which == "fused_ragged":
             T = -(-(B * C) // self.pack_align) * self.pack_align
             flat = jnp.zeros((T,), jnp.int32)
@@ -699,20 +666,13 @@ class GenerationEngine:
                 tables, flat, flat, flat, flat, flat,
                 flat, jnp.zeros((B,), jnp.int32),
             )
-        if which == "fused_padded":
-            tables = jnp.full((B, self._view_blocks), self._null_block,
-                              jnp.int32)
-            return self._fused_step_jit, (
-                self.params, k, v, self.kv.k_scale, self.kv.v_scale,
-                tables, tokens, starts, n_valid, seg, seg, seg,
-            )
         if which in ("decode", "decode_ref"):
             tables = jnp.full((B, self.max_blocks), self._null_block, jnp.int32)
             jitted = (self._decode_dispatch_jit if which == "decode"
                       else self._decode_paged_jit)
             return jitted, (
                 self.params, k, v, self.kv.k_scale, self.kv.v_scale,
-                tables, tokens[:, :1], starts,
+                tables, jnp.zeros((B, 1), jnp.int32), starts,
             )
         if which == "pool":
             bs = self.block_size
@@ -726,6 +686,7 @@ class GenerationEngine:
 
             G, KVH, hd = k.shape[0], k.shape[3], k.shape[4]
             new_kv = jnp.zeros((G, B, C, KVH, hd), k.dtype)
+            n_valid = jnp.ones((B,), jnp.int32)
             tables = jnp.full((B, self._view_blocks), self._null_block, jnp.int32)
             if self.pool_layout is not None:
                 pool_s = self.pool_layout.pool_sharding(self.cfg, self.kv.pool.n_blocks)
@@ -741,7 +702,7 @@ class GenerationEngine:
         """Compile one of the engine's step programs against representative
         inputs and census its collective ops (models.shardmap_tp
         .count_collectives) — the schedule audit behind the sharded-pool
-        contract: ``"fused"`` (the interleaved mixed batch) and ``"decode"``
+        contract: ``"fused"`` (the packed mixed batch) and ``"decode"``
         (block-table batched decode) must show ZERO all-gathers — the
         gather/scatter over host-resident block tables never communicates —
         and only the Megatron all-reduces; ``"pool"`` (a bare
@@ -753,8 +714,7 @@ class GenerationEngine:
         the same step_program() targets."""
         from repro.models.shardmap_tp import count_collectives
 
-        alias = {"fused": "fused_ragged" if self.ragged else "fused_padded",
-                 "decode": "decode_ref"}
+        alias = {"fused": "fused_ragged", "decode": "decode_ref"}
         jitted, args = self.step_program(alias.get(which, which))
         return count_collectives(jitted.lower(*args).compile())
 
@@ -901,8 +861,6 @@ class GenerationEngine:
         return min(len(req.prompt), self.max_seq)
 
     def _try_admit(self, req: Request) -> bool:
-        if self.backend != "paged":
-            return True  # dense: a free slot is the only admission resource
         if req.swapped:
             return self._swap_in(req)
         cap = self._prompt_cap(req)
@@ -1097,93 +1055,6 @@ class GenerationEngine:
             self.kv.k_scale = k_sc
             self.kv.v_scale = v_sc
 
-    def _prefill_chunk_fn(self, params, k_pool, v_pool, k_sc, v_sc, table_row,
-                          tokens, start, n_valid, positions, p_end, s_start):
-        """One chunked-prefill step for a single request (B=1): gather the
-        sequence view, run the chunk through the stack, scatter its K/V back
-        into the pool (padding rerouted to the scratch block).
-        ``positions``/``p_end``/``s_start`` (1, C) carry the segmented-prompt
-        rope positions and attention spans (see serving.segments).
-        ``k_sc``/``v_sc`` are the (G, n_blocks, KVH) scale pools of an int8
-        pool (None for float pools): the view gather dequantizes and the
-        write-back requantizes under the running per-block absmax. All paged
-        step programs return (logits, k_pool, v_pool, k_sc, v_sc)."""
-        kview = gather_paged_batch_dq(k_pool, k_sc, table_row[None],
-                                      out_dtype=jnp.dtype(self.cfg.dtype))
-        vview = gather_paged_batch_dq(v_pool, v_sc, table_row[None],
-                                      out_dtype=jnp.dtype(self.cfg.dtype))
-        caches = ({"k": kview, "v": vview},)
-        logits, new_caches = prefill_chunk(
-            self._oracle_cfg, params, caches, tokens, start, positions, p_end,
-            s_start
-        )
-        pc = tokens.shape[1]
-        newk = jax.lax.dynamic_slice_in_dim(new_caches[0]["k"], start, pc, axis=2)[:, 0]
-        newv = jax.lax.dynamic_slice_in_dim(new_caches[0]["v"], start, pc, axis=2)[:, 0]
-        if k_sc is None:
-            k_pool = write_paged_chunk(
-                k_pool, table_row, start, newk, self.block_size, n_valid,
-                self._null_block
-            )
-            v_pool = write_paged_chunk(
-                v_pool, table_row, start, newv, self.block_size, n_valid,
-                self._null_block
-            )
-        else:
-            k_pool, k_sc = write_paged_chunk_q(
-                k_pool, k_sc, table_row, start, newk, self.block_size,
-                n_valid, self._null_block
-            )
-            v_pool, v_sc = write_paged_chunk_q(
-                v_pool, v_sc, table_row, start, newv, self.block_size,
-                n_valid, self._null_block
-            )
-        return logits[0, n_valid - 1], k_pool, v_pool, k_sc, v_sc
-
-    def _fused_step_fn(self, params, k_pool, v_pool, k_sc, v_sc, tables,
-                       tokens, starts, n_valid, positions, p_end, s_start):
-        """One fused interleaved step: every row is a chunk at its own cursor —
-        decode rows carry one valid token at slot ``starts[b]``, prefill
-        rows carry ``n_valid[b]`` prompt tokens. Gather each row's sequence
-        view, run one batched chunked forward, scatter all rows' new K/V back
-        into the pool (padding rerouted to the scratch block), and return each
-        row's last-valid-token logits. ``positions``/``p_end``/``s_start``
-        (B, C) carry per-row segmented-prompt rope positions and attention
-        spans (flat rows: positions == slots, spans zero)."""
-        kview = gather_paged_batch_dq(k_pool, k_sc, tables,
-                                      out_dtype=jnp.dtype(self.cfg.dtype))  # (G,B,Sv,KVH,hd)
-        vview = gather_paged_batch_dq(v_pool, v_sc, tables,
-                                      out_dtype=jnp.dtype(self.cfg.dtype))
-        caches = ({"k": kview, "v": vview},)
-        logits, new_caches = prefill_chunk(
-            self._oracle_cfg, params, caches, tokens, starts, positions,
-            p_end, s_start
-        )
-        B, C = tokens.shape
-        b = jnp.arange(B)
-        idx = starts[:, None] + jnp.arange(C)                 # (B, C) view slots
-        newk = new_caches[0]["k"][:, b[:, None], idx]          # (G,B,C,KVH,hd)
-        newv = new_caches[0]["v"][:, b[:, None], idx]
-        if k_sc is None:
-            k_pool = write_paged_chunk_batch(
-                k_pool, tables, starts, newk, self.block_size, n_valid,
-                self._null_block
-            )
-            v_pool = write_paged_chunk_batch(
-                v_pool, tables, starts, newv, self.block_size, n_valid,
-                self._null_block
-            )
-        else:
-            k_pool, k_sc = write_paged_chunk_batch_q(
-                k_pool, k_sc, tables, starts, newk, self.block_size, n_valid,
-                self._null_block
-            )
-            v_pool, v_sc = write_paged_chunk_batch_q(
-                v_pool, v_sc, tables, starts, newv, self.block_size, n_valid,
-                self._null_block
-            )
-        return logits[b, jnp.maximum(n_valid - 1, 0)], k_pool, v_pool, k_sc, v_sc
-
     def _ragged_step_fn(self, params, k_pool, v_pool, k_sc, v_sc, tables,
                         tokens, row_of, slots, positions, p_end, s_start,
                         last_idx):
@@ -1245,59 +1116,6 @@ class GenerationEngine:
             return flat.at[:, dest].set(new.astype(flat.dtype)).reshape(pool.shape)
 
         return logits, scatter(k_pool, newk), scatter(v_pool, newv), None, None
-
-    def _seg_arrays(self, req: Request, pos: int, c: int, width: int) -> tuple:
-        """(positions, p_end, s_start) (1, width) slices of the request's
-        layout at [pos, pos+c) — the segmented-prompt rope positions and
-        attention spans for one chunk (padding columns are masked out by
-        n_valid downstream; zeros are fine there)."""
-        positions = np.zeros((1, width), np.int32)
-        p_end = np.zeros((1, width), np.int32)
-        s_start = np.zeros((1, width), np.int32)
-        lay = req.layout
-        positions[0, :c] = lay.pos_ids[pos : pos + c]
-        p_end[0, :c] = lay.attn_p_end[pos : pos + c]
-        s_start[0, :c] = lay.attn_s_start[pos : pos + c]
-        return positions, p_end, s_start
-
-    def _prefill_paged(self, req: Request, slot: int):
-        cap = self._prompt_cap(req)
-        req.truncated = cap < len(req.prompt)
-        toks = np.asarray(req.prompt[:cap], np.int32)
-        pc = self.prefill_chunk_size
-        # pad-ok: prefill gathers only blocks already reserved for this
-        # request; gather_paged_batch clamps pads inside the jitted fn.
-        table = jnp.asarray(
-            self.kv.pool.table_array([req.req_id], self._view_blocks)[0]
-        )
-        req.prefill_cap = cap
-        req.prefill_pos = 0
-        _advance_cursor(req)  # shared blocks already carry their K/V
-        last = None
-        while req.prefill_pos < cap:
-            pos = req.prefill_pos
-            C = _max_grant(req, pc)
-            chunk = np.zeros((1, pc), np.int32)
-            chunk[0, :C] = toks[pos : pos + C]
-            positions, p_end, s_start = self._seg_arrays(req, pos, C, pc)
-            last, *pools = self._prefill_chunk_jit(
-                self.params, self.kv.k, self.kv.v, self.kv.k_scale,
-                self.kv.v_scale, table, jnp.asarray(chunk),
-                pos, C, jnp.asarray(positions), jnp.asarray(p_end),
-                jnp.asarray(s_start),
-            )
-            self._set_pools(*pools)
-            req.prefill_pos = pos + C
-            self.prefill_tokens += C
-            _advance_cursor(req)
-        self.kv.lengths[req.req_id] = cap
-        self.kv.register_prefix(req.req_id, toks, req.layout)
-        req.slot = slot
-        req.pos = cap
-        req.prefill_pos = cap
-        self._key, sk = jax.random.split(self._key)
-        tok = int(sample_tokens(sk, jnp.asarray(last)[None], req.temperature)[0])
-        self._emit(req, tok)
 
     def _preempt(self, victim: Request):
         """Apply the engine's preemption strategy to ``victim``.
@@ -1402,17 +1220,17 @@ class GenerationEngine:
 
     # ------------------------------------------------------------- stepping
     def step(self) -> Dict[int, List[int]]:
-        """One engine iteration. Interleaved paged mode: the control plane
-        builds one StepPlan (admission + fused mixed batch) and the device
-        runner dispatches it; sampled tokens materialize this step
+        """One engine iteration. Paged backend: the control plane builds one
+        StepPlan (admission + packed mixed batch or decode batch) and the
+        device runner dispatches it; sampled tokens materialize this step
         (``pipeline=False``, the sync oracle) or next step (``pipeline=True``,
-        double-buffered). Sequential mode: admit (blocking whole-prompt
+        double-buffered). Dense backend: admit (blocking whole-prompt
         prefill), then one batched decode. Returns the tokens whose emission
         LANDED this step — in pipelined mode that is the previous plan's."""
         with self.telemetry.span("engine.step"):
             for r in self.waiting:
                 r.queued_steps += 1
-            if self.interleave:
+            if self.backend == "paged":
                 return self._step_planned()
             out = self._step_sequential()
             self._drain_copies(full=True)
@@ -1498,33 +1316,14 @@ class GenerationEngine:
             self._copy.drain(None if full else self.copy_budget)
 
     def _step_sequential(self) -> Dict[int, List[int]]:
-        blocked = False
+        """Dense backend: fill free slots in policy order, each with a
+        blocking whole-prompt prefill, then one batched decode."""
         for slot in range(self.max_batch):
-            while self.slots[slot] is None and self.waiting and not blocked:
-                i = self.scheduler.select(self.waiting)
-                req = self.waiting[i]
-                was_swapped = req.swapped  # _try_admit clears it on restore
-                if not self._try_admit(req):
-                    if req.done:  # unfittable request failed out; try the next
-                        self.waiting.pop(i)
-                        continue
-                    self.admit_blocked += 1
-                    blocked = True  # the policy's head-of-line waits for blocks
-                    break
-                self.waiting.pop(i)
+            while self.slots[slot] is None and self.waiting:
+                req = self.waiting.pop(self.scheduler.select(self.waiting))
                 self.slots[slot] = req
                 self._mark_admitted(req)
-                if was_swapped:
-                    # restored in place: KV, position and cursor resume as
-                    # they were (sequential victims are always decode-phase)
-                    req.slot = slot
-                elif self.backend == "paged":
-                    self._prefill_paged(req, slot)
-                else:
-                    self._prefill_one(req, slot)
-
-        if self.backend == "paged":
-            self._ensure_decode_capacity()
+                self._prefill_one(req, slot)
         active = [r for r in self.slots if r is not None]
         if not active:
             return {}
@@ -1558,7 +1357,7 @@ class GenerationEngine:
         return False
 
     def _decode_batch(self, active: List[Request]) -> Dict[int, List[int]]:
-        """One batched decode over the active decode-phase slots."""
+        """One batched decode over the dense cache's active slots."""
         tokens = np.zeros((self.max_batch, 1), np.int32)
         pos = np.zeros((self.max_batch,), np.int32)
         temps = np.zeros((self.max_batch,), np.float32)
@@ -1567,24 +1366,9 @@ class GenerationEngine:
             pos[r.slot] = r.pos
             temps[r.slot] = r.temperature
 
-        if self.backend == "paged":
-            tables = np.full((self.max_batch, self.max_blocks), self._null_block, np.int32)
-            rows = self.kv.batch_tables([r.req_id for r in active])
-            for i, r in enumerate(active):
-                valid = rows[i] >= 0
-                tables[r.slot, valid] = rows[i][valid]
-            logits, *pools = self._decode_dispatch_jit(
-                self.params, self.kv.k, self.kv.v, self.kv.k_scale,
-                self.kv.v_scale,
-                jnp.asarray(tables), jnp.asarray(tokens), jnp.asarray(pos),
-            )
-            self._set_pools(*pools)
-            for r in active:
-                self.kv.lengths[r.req_id] = r.pos + 1
-        else:
-            logits, self.cache = self._decode_jit(
-                self.params, self.cache, jnp.asarray(tokens), jnp.asarray(pos)
-            )
+        logits, self.cache = self._decode_jit(
+            self.params, self.cache, jnp.asarray(tokens), jnp.asarray(pos)
+        )
         self.steps += 1
         self._key, sk = jax.random.split(self._key)
         emitted: Dict[int, List[int]] = {}
@@ -1633,7 +1417,7 @@ class GenerationEngine:
     def _finalize(self, req: Request):
         """Completion side effects (idempotent): done flag, finished window,
         stream close — plus slot/block release for paths that did not already
-        retire at plan-build time (sequential mode, eos hits)."""
+        retire at plan-build time (the dense backend, eos hits)."""
         if req.done:
             return
         req.done = True
@@ -1651,7 +1435,7 @@ class GenerationEngine:
             req.stream.close()
 
     def _emit(self, req: Request, tok: int):
-        """Eager emit (sequential + dense paths): token side effects plus the
+        """Eager emit (dense backend): token side effects plus the
         historical completion check applied immediately."""
         self._emit_token(req, tok)
         req.planned = len(req.out_tokens)

@@ -195,8 +195,9 @@ class PagedPool:
         * device-side consumers must therefore treat negatives as absent:
           gathers clamp (``gather_paged_batch``/``paged_validity``), scatters
           re-route padded slots to the scratch block
-          (``write_paged_chunk_batch``). The engine's fused step additionally
-          rewrites ``-1`` entries to its scratch block id before tracing.
+          (``write_paged_chunk_batch``). The engine's decode step
+          additionally rewrites ``-1`` entries to its scratch block id
+          before tracing; its packed mixed step masks them.
         """
         out = np.full((len(seq_ids), max_blocks), -1, dtype=np.int32)
         for i, sid in enumerate(seq_ids):
@@ -250,8 +251,7 @@ def write_paged_chunk(pool_kv, block_table_row, start, new_kv, block_size: int,
 def write_paged_chunk_batch(pool_kv, block_tables, starts, new_kv, block_size: int,
                             n_valid=None, null_dest: int = 0):
     """Multi-row chunk scatter: write B sequences' C-token chunks in one
-    update (the fused interleaved-step path — decode rows are chunks with
-    ``n_valid == 1``).
+    update (the engine's audited ``pool`` step program).
 
     pool_kv: (G, n_blocks, bs, KVH, hd); block_tables: (B, mb) int32;
     starts/n_valid: (B,) absolute start position and valid-token count per
@@ -347,25 +347,6 @@ def write_paged_chunk_q(pool_kv, scales, block_table_row, start, new_kv,
     if n_valid is not None:
         dest = jnp.where(jnp.arange(C) < n_valid, dest, null_dest * bs)
     return _quantized_scatter(pool_kv, scales, dest, new_kv)
-
-
-def write_paged_chunk_batch_q(pool_kv, scales, block_tables, starts, new_kv,
-                              block_size: int, n_valid=None,
-                              null_dest: int = 0):
-    """Quantized ``write_paged_chunk_batch``: multi-row chunk scatter into an
-    int8 pool. Returns ``(pool, scales)``."""
-    G, bs = pool_kv.shape[0], pool_kv.shape[2]
-    B, C = new_kv.shape[1], new_kv.shape[2]
-    pos = starts[:, None] + jnp.arange(C)                      # (B, C)
-    blk = jnp.take_along_axis(block_tables, pos // bs, axis=1)
-    dest = jnp.maximum(blk, 0) * bs + pos % bs
-    if n_valid is not None:
-        dest = jnp.where(jnp.arange(C)[None, :] < n_valid[:, None], dest,
-                         null_dest * bs)
-    return _quantized_scatter(
-        pool_kv, scales, dest.reshape(-1),
-        new_kv.reshape(G, B * C, *new_kv.shape[3:]),
-    )
 
 
 def write_paged_packed_q(pool_kv, scales, block_tables, row_of, slots, new_kv,

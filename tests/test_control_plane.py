@@ -25,22 +25,29 @@ def _engine(**kw):
 
 # --------------------------------------------------------------- StepPlan
 def test_build_plan_fused_shape_and_grants():
-    eng = _engine(ragged=False)
-    a = eng.submit(np.arange(4) % 90, max_new=8)          # short: completes
-    b = eng.submit(np.arange(40) % 90 + 1, max_new=2)     # long: mid-prefill
+    """A plan with no prefill row is a decode plan: one token a row and
+    scratch-filled (B, max_blocks) tables, every active row emitting."""
+    eng = _engine()
+    a = eng.submit(np.arange(4) % 90, max_new=8)
+    b = eng.submit(np.arange(6) % 90 + 1, max_new=8)
+    assert eng.control.build_plan().kind == "ragged"  # both prompts prefill
     plan = eng.control.build_plan()
-    assert plan is not None and plan.kind == "fused"
-    assert plan.tokens.shape == (eng.max_batch, eng.prefill_chunk_size)
+    assert plan is not None and plan.kind == "decode"
+    B = eng.max_batch
+    assert plan.tokens.shape == (B, 1) and plan.starts.shape == (B,)
+    assert plan.tables.shape == (B, eng.max_blocks)
     assert plan.tokens.dtype == np.int32 and plan.tables.dtype == np.int32
-    # a's 4-token prompt fits one chunk -> prefill completes at build time,
-    # so it appears in emit_rows; b got the remaining budget but is not done
-    assert a.prefill_pos == 4 and a.pos == 4
-    emitted = {r.req_id for r, _row, _fin in plan.emit_rows}
-    assert a.req_id in emitted and b.req_id not in emitted
-    assert 0 < b.prefill_pos < len(b.prompt)
-    # grants respect the budget: total valid tokens <= token_budget
-    assert plan.n_tokens <= eng.token_budget
-    assert int(plan.n_valid.sum()) == plan.n_tokens
+    # no packed arrays on a decode plan
+    assert plan.row_of is None and plan.n_valid is None
+    # every decode row emits; positions were advanced at build time
+    assert {r.req_id for r, _row, _fin in plan.emit_rows} == {a.req_id, b.req_id}
+    assert plan.n_tokens == 2
+    assert plan.starts[a.slot] == 4 and a.pos == 5
+    assert plan.starts[b.slot] == 6 and b.pos == 7
+    # rows the plan left empty, and table entries past a chain, hold the
+    # scratch block
+    free = [s for s in range(B) if s not in (a.slot, b.slot)]
+    assert np.all(plan.tables[free] == eng._null_block)
     # nothing emitted yet: emission happens at materialize, not build
     assert a.out_tokens == [] and not a.done
 
@@ -75,7 +82,7 @@ def test_build_plan_ragged_shape_and_grants():
 
 
 def test_build_plan_marks_device_resident_prev_tokens():
-    eng = _engine(ragged=False)
+    eng = _engine()
     r = eng.submit(np.arange(4) % 90, max_new=8)
     eng.step()  # plan 0 dispatched: r's first token lives on device
     plan = eng.control.build_plan()

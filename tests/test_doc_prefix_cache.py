@@ -214,18 +214,22 @@ def test_flat_chained_hash_misses_on_reorder():
 
 
 def test_segmented_interleave_modes_agree():
+    """Segmented prompts give the same greedy tokens whether sampled tokens
+    materialize eagerly (the sync oracle) or one plan late (pipelined)."""
     cfg = _cfg()
     prompt, rng = _doc_prompts(n_docs=3)
     orders = [[0, 1, 2], [2, 1, 0]]
     queries = [rng.integers(0, 300, 8) for _ in orders]
     outs = {}
-    for interleave in (False, True):
+    for pipeline in (False, True):
         eng = GenerationEngine(cfg, max_batch=2, max_seq=256,
-                               interleave=interleave, prefill_chunk_size=32)
+                               pipeline=pipeline, prefill_chunk_size=32)
         reqs = [eng.submit(prompt(o, q), max_new=5)
                 for o, q in zip(orders, queries)]
         eng.run_until_done()
-        outs[interleave] = [r.out_tokens for r in reqs]
+        assert eng.pipeline == pipeline
+        assert reqs[1].shared_prefix_tokens > 0  # the docs really re-shared
+        outs[pipeline] = [r.out_tokens for r in reqs]
     assert outs[True] == outs[False]
 
 

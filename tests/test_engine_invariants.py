@@ -14,10 +14,10 @@ def _cfg():
     return smoke_variant(get_arch("smollm-135m"))
 
 
-def _run_workload(seed: int, *, n_blocks, scheduler: str, interleave: bool,
+def _run_workload(seed: int, *, n_blocks, scheduler: str,
                   long_decode: bool = False, preempt: str = "recompute",
                   pipeline: bool = True, kernel: str = "reference",
-                  ragged: bool = True, kv_dtype: str = None,
+                  kv_dtype: str = None,
                   greedy: bool = False, sanitize: bool = False):
     """Bursty seeded workload: waves of submits interleaved with engine steps.
     Prompts mix fresh random sequences with shared-retrieved-context prefixes
@@ -28,8 +28,8 @@ def _run_workload(seed: int, *, n_blocks, scheduler: str, interleave: bool,
     eng = GenerationEngine(
         _cfg(), max_batch=3, max_seq=96, n_blocks=n_blocks,
         prefill_chunk_size=16, token_budget=20,
-        scheduler=scheduler, interleave=interleave, preempt=preempt,
-        pipeline=pipeline, kernel=kernel, ragged=ragged, kv_dtype=kv_dtype,
+        scheduler=scheduler, preempt=preempt,
+        pipeline=pipeline, kernel=kernel, kv_dtype=kv_dtype,
         sanitize=sanitize,
     )
     ctx = rng.integers(0, 90, size=32).astype(np.int32)
@@ -59,26 +59,26 @@ def _run_workload(seed: int, *, n_blocks, scheduler: str, interleave: bool,
 
 
 @pytest.mark.parametrize(
-    "seed,n_blocks,scheduler,interleave,long_decode,preempt",
+    "seed,n_blocks,scheduler,long_decode,preempt",
     [
-        (0, None, "fifo", True, False, "recompute"),   # fully provisioned pool
-        (1, None, "edf_slack", True, False, "recompute"),  # EDF admission + grants
-        (2, 8, "fifo", True, False, "recompute"),      # tiny pool: backpressure
-        (3, 8, "fifo", False, False, "recompute"),     # sequential oracle
-        (4, 10, "edf_slack", True, False, "recompute"),
-        (5, 6, "fifo", True, True, "recompute"),       # long decodes: preemption
-        (5, 6, "fifo", True, True, "swap"),            # swap-out preemption tier
-        (6, 6, "edf_slack", True, True, "swap"),
-        (3, 8, "fifo", False, False, "swap"),          # sequential + swap
-        (2, 8, "resident_first", True, False, "recompute"),  # eviction-aware
-        (5, 6, "fifo", True, True, "cost"),            # per-victim cost model
-        (6, 6, "edf_slack", True, True, "cost"),
+        (0, None, "fifo", False, "recompute"),   # fully provisioned pool
+        (1, None, "edf_slack", False, "recompute"),  # EDF admission + grants
+        (2, 8, "fifo", False, "recompute"),      # tiny pool: backpressure
+        (3, 8, "fifo", False, "recompute"),
+        (4, 10, "edf_slack", False, "recompute"),
+        (5, 6, "fifo", True, "recompute"),       # long decodes: preemption
+        (5, 6, "fifo", True, "swap"),            # swap-out preemption tier
+        (6, 6, "edf_slack", True, "swap"),
+        (3, 8, "fifo", False, "swap"),           # swap tier without churn
+        (2, 8, "resident_first", False, "recompute"),  # eviction-aware
+        (5, 6, "fifo", True, "cost"),            # per-victim cost model
+        (6, 6, "edf_slack", True, "cost"),
     ],
 )
-def test_engine_invariants_after_drain(seed, n_blocks, scheduler, interleave,
+def test_engine_invariants_after_drain(seed, n_blocks, scheduler,
                                        long_decode, preempt):
     eng, reqs = _run_workload(
-        seed, n_blocks=n_blocks, scheduler=scheduler, interleave=interleave,
+        seed, n_blocks=n_blocks, scheduler=scheduler,
         long_decode=long_decode, preempt=preempt,
     )
     if long_decode:
@@ -150,7 +150,7 @@ def test_invariants_under_kv_sanitizer(seed, n_blocks, preempt, pipeline,
     swap-order). On drain the shadow must agree with the real pool: only
     the scratch block allocated, warm set sizes matching."""
     eng, reqs = _run_workload(
-        seed, n_blocks=n_blocks, scheduler="fifo", interleave=True,
+        seed, n_blocks=n_blocks, scheduler="fifo",
         long_decode=n_blocks is not None, preempt=preempt,
         pipeline=pipeline, kv_dtype=kv_dtype, sanitize=True)
     san = eng.sanitizer
@@ -188,10 +188,10 @@ def test_pipelined_matches_sync_oracle(seed, n_blocks, preempt, scheduler,
     the PRNG key splits once per dispatch, sampled-token-identical) to the
     synchronous oracle — including across swap preemption and re-admission."""
     sync_eng, sync_reqs = _run_workload(
-        seed, n_blocks=n_blocks, scheduler=scheduler, interleave=True,
+        seed, n_blocks=n_blocks, scheduler=scheduler,
         long_decode=long_decode, preempt=preempt, pipeline=False)
     pip_eng, pip_reqs = _run_workload(
-        seed, n_blocks=n_blocks, scheduler=scheduler, interleave=True,
+        seed, n_blocks=n_blocks, scheduler=scheduler,
         long_decode=long_decode, preempt=preempt, pipeline=True)
     assert not sync_eng.pipeline and pip_eng.pipeline
     if long_decode:
@@ -222,14 +222,14 @@ def test_pallas_kernel_matches_reference(seed, n_blocks, scheduler,
     workloads — including across swap preemption and pipelined dispatch —
     and the pool must drain clean."""
     ref_eng, ref_reqs = _run_workload(
-        seed, n_blocks=n_blocks, scheduler=scheduler, interleave=True,
+        seed, n_blocks=n_blocks, scheduler=scheduler,
         long_decode=long_decode, preempt=preempt, pipeline=pipeline,
         kernel="reference")
     pal_eng, pal_reqs = _run_workload(
-        seed, n_blocks=n_blocks, scheduler=scheduler, interleave=True,
+        seed, n_blocks=n_blocks, scheduler=scheduler,
         long_decode=long_decode, preempt=preempt, pipeline=pipeline,
         kernel="pallas")
-    assert pal_eng.kernel == "pallas" and pal_eng.ragged
+    assert pal_eng.kernel == "pallas"
     if long_decode:
         assert pal_eng.preemptions >= 1
     for a, b in zip(ref_reqs, pal_reqs):
@@ -242,8 +242,6 @@ def test_pallas_kernel_matches_reference(seed, n_blocks, scheduler,
 def test_pallas_kernel_rejects_unsupported_modes():
     from repro.configs import get_arch, smoke_variant
     cfg = smoke_variant(get_arch("smollm-135m"))
-    with pytest.raises(ValueError):
-        GenerationEngine(cfg, kernel="pallas", ragged=False)
     with pytest.raises(ValueError):
         GenerationEngine(cfg, kernel="mosaic-gpu")
 
@@ -334,7 +332,7 @@ INT8_GREEDY_FLOOR = 0.75
     [
         (5, 6, "swap", True, True),    # forced preemption: host-tier scale
                                        # round-trip + pipelined dispatch
-        (5, 6, "swap", False, True),   # same churn, sequential sync oracle
+        (5, 6, "swap", False, True),   # same churn, sync oracle
         (4, 8, "recompute", True, False),  # backpressure, no preemption
     ],
 )
@@ -345,11 +343,11 @@ def test_int8_pool_greedy_agreement(seed, n_blocks, preempt, pipeline,
     the host tier verbatim) and pipelined dispatch — and drain the pool as
     clean as the float path."""
     fp_eng, fp_reqs = _run_workload(
-        seed, n_blocks=n_blocks, scheduler="fifo", interleave=True,
+        seed, n_blocks=n_blocks, scheduler="fifo",
         long_decode=long_decode, preempt=preempt, pipeline=pipeline,
         greedy=True)
     q_eng, q_reqs = _run_workload(
-        seed, n_blocks=n_blocks, scheduler="fifo", interleave=True,
+        seed, n_blocks=n_blocks, scheduler="fifo",
         long_decode=long_decode, preempt=preempt, pipeline=pipeline,
         kv_dtype="int8", greedy=True)
     assert q_eng.kv_dtype == "int8" and q_eng.kv.quantized
@@ -372,10 +370,10 @@ def test_int8_pipelined_matches_sync_oracle():
     identical to the sync oracle across swap preemption — the quantized
     state (pools AND scale pools) round-trips the host tier exactly."""
     sync_eng, sync_reqs = _run_workload(
-        5, n_blocks=6, scheduler="fifo", interleave=True, long_decode=True,
+        5, n_blocks=6, scheduler="fifo", long_decode=True,
         preempt="swap", pipeline=False, kv_dtype="int8")
     pip_eng, pip_reqs = _run_workload(
-        5, n_blocks=6, scheduler="fifo", interleave=True, long_decode=True,
+        5, n_blocks=6, scheduler="fifo", long_decode=True,
         preempt="swap", pipeline=True, kv_dtype="int8")
     assert pip_eng.preemptions >= 1 and pip_eng.swap_ins >= 1
     for a, b in zip(sync_reqs, pip_reqs):
@@ -387,10 +385,10 @@ def test_int8_pallas_kernel_matches_reference():
     """kernel="pallas" on int8 pools (dequant inside the kernel) must be
     token-identical to the XLA reference path on the same workload."""
     ref_eng, ref_reqs = _run_workload(
-        2, n_blocks=8, scheduler="fifo", interleave=True,
+        2, n_blocks=8, scheduler="fifo",
         kv_dtype="int8", kernel="reference")
     pal_eng, pal_reqs = _run_workload(
-        2, n_blocks=8, scheduler="fifo", interleave=True,
+        2, n_blocks=8, scheduler="fifo",
         kv_dtype="int8", kernel="pallas")
     assert pal_eng.kernel == "pallas" and pal_eng.kv.quantized
     for a, b in zip(ref_reqs, pal_reqs):
@@ -412,7 +410,7 @@ def test_quant_config_routes_to_paged_backend():
     assert r.done and len(r.out_tokens) == 4
 
 
-# ----------------------------------------------- ragged layout round-trip
+# ------------------------------------------------------ ragged packing
 def _unpack_ragged(plan, B):
     """Pure-numpy unpacker: rebuild each row's chunk from the flat packed
     buffer. Validates the packing invariants on the way: rows are contiguous
@@ -442,80 +440,87 @@ def _unpack_ragged(plan, B):
     return out
 
 
-def _capture_plans(eng):
-    plans = []
-    orig = eng.control.build_plan
-
-    def wrapped():
-        p = orig()
-        if p is not None:
-            plans.append(p)
-        return p
-
-    eng.control.build_plan = wrapped
-    return plans
-
-
 @pytest.mark.parametrize("seed,n_blocks", [(0, None), (2, 8)])
-def test_ragged_plan_round_trips_to_padded_layout(seed, n_blocks):
-    """The packed layout is a pure re-encoding: a numpy unpacker applied to
-    every ragged StepPlan must reconstruct exactly the per-row chunks the
-    padded assembler emits for the same workload, step for step — and the
-    drained token outputs must be bit-identical."""
+def test_ragged_plan_rows_match_requests_at_their_cursors(seed, n_blocks):
+    """The packed layout is a pure re-encoding of each step's rows: a numpy
+    unpacker applied to every ragged StepPlan must find each prefill row's
+    own prompt tokens, cache slots, rope positions and attention spans at
+    the cursor it had when the plan was built, each decode row's single
+    token at its position, and grants inside the token budget, the chunk
+    size, the prompt and the next cache-served span."""
     rng = np.random.default_rng(seed)
     prompts = [rng.integers(0, 90, size=int(rng.integers(3, 40)))
                for _ in range(6)]
     max_new = [int(rng.integers(2, 9)) for _ in prompts]
+    eng = GenerationEngine(
+        _cfg(), max_batch=3, max_seq=96, n_blocks=n_blocks,
+        prefill_chunk_size=16, token_budget=20,
+    )
+    B, C = eng.max_batch, eng.prefill_chunk_size
+    seen = []
+    assemble = eng.control._assemble_ragged
 
-    def run(ragged):
-        eng = GenerationEngine(
-            _cfg(), max_batch=3, max_seq=96, n_blocks=n_blocks,
-            prefill_chunk_size=16, token_budget=20, ragged=ragged,
-        )
-        plans = _capture_plans(eng)
-        reqs = [eng.submit(p, max_new=m) for p, m in zip(prompts, max_new)]
-        eng.run_until_done(max_steps=1000)
-        return eng, reqs, plans
+    def spy(plan_id, active, *rest):
+        # each row's request state as the assembler reads it
+        rows = {r.slot: (r.prefilling, r.prefill_pos, r.pos, r.prefill_cap,
+                         r.prompt, r.layout, list(r.shared_spans),
+                         r.out_tokens[-1] if r.out_tokens else 0)
+                for r in active}
+        seen.append((assemble(plan_id, active, *rest), rows))
+        return seen[-1][0]
 
-    rag_eng, rag_reqs, rag_plans = run(True)
-    pad_eng, pad_reqs, pad_plans = run(False)
+    eng.control._assemble_ragged = spy
+    reqs = [eng.submit(p, max_new=m) for p, m in zip(prompts, max_new)]
+    eng.run_until_done(max_steps=1000)
+    assert all(r.done for r in reqs)
+    assert seen, "workload never produced a mixed/prefill plan"
 
-    assert len(rag_plans) == len(pad_plans)
-    saw_ragged = False
-    for rp, fp in zip(rag_plans, pad_plans):
-        if fp.kind == "decode":       # decode-only plans share one assembler
-            assert rp.kind == "decode"
-            np.testing.assert_array_equal(rp.tokens, fp.tokens)
-            np.testing.assert_array_equal(rp.tables, fp.tables)
-            continue
-        assert rp.kind == "ragged" and fp.kind == "fused"
-        saw_ragged = True
-        # the packed buffer never exceeds the padded slab, and its tail
-        # alignment is the only padding
-        assert rp.tokens.shape[0] <= fp.tokens.shape[0] * fp.tokens.shape[1]
-        assert rp.tokens.shape[0] % rag_eng.pack_align == 0
-        np.testing.assert_array_equal(rp.n_valid, fp.n_valid)
-        np.testing.assert_array_equal(rp.starts, fp.starts)
-        chunks = _unpack_ragged(rp, rag_eng.max_batch)
-        for b in range(rag_eng.max_batch):
-            nv = int(fp.n_valid[b])
+    slot_tokens = valid_tokens = 0
+    for plan, rows in seen:
+        T = plan.tokens.shape[0]
+        assert T % eng.pack_align == 0 and T < plan.n_tokens + eng.pack_align
+        slot_tokens += T
+        valid_tokens += plan.n_tokens
+        assert int(plan.n_valid.sum()) == plan.n_tokens
+        chunks = _unpack_ragged(plan, B)
+        n_decode = sum(not row[0] for row in rows.values())
+        granted = 0
+        for b, (prefilling, cursor, pos, cap, prompt, lay, spans,
+                host_tok) in rows.items():
+            nv = int(plan.n_valid[b])
+            if not prefilling:
+                ch = chunks[b]
+                assert nv == 1 and plan.starts[b] == pos
+                want_tok = 0 if plan.prev_slots[b] >= 0 else host_tok
+                np.testing.assert_array_equal(ch["tokens"], [want_tok])
+                np.testing.assert_array_equal(ch["slots"], [pos])
+                np.testing.assert_array_equal(ch["positions"], [pos])
+                np.testing.assert_array_equal(ch["p_end"], [0])
+                np.testing.assert_array_equal(ch["s_start"], [0])
+                continue
+            assert plan.starts[b] == cursor and plan.decode_idx[b] == -1
+            granted += nv
+            assert nv <= min(C, cap - cursor)
+            assert not any(cursor < s < cursor + nv for s, _e in spans)
             if nv == 0:
                 assert b not in chunks
                 continue
             ch = chunks[b]
-            np.testing.assert_array_equal(ch["tokens"], fp.tokens[b, :nv])
-            np.testing.assert_array_equal(ch["positions"], fp.positions[b, :nv])
-            np.testing.assert_array_equal(ch["p_end"], fp.p_end[b, :nv])
-            np.testing.assert_array_equal(ch["s_start"], fp.s_start[b, :nv])
-            np.testing.assert_array_equal(
-                ch["slots"], np.arange(fp.starts[b], fp.starts[b] + nv))
-    assert saw_ragged, "workload never produced a mixed/prefill plan"
+            sl = slice(cursor, cursor + nv)
+            np.testing.assert_array_equal(ch["tokens"], prompt[sl])
+            np.testing.assert_array_equal(ch["slots"],
+                                          np.arange(cursor, cursor + nv))
+            np.testing.assert_array_equal(ch["positions"], lay.pos_ids[sl])
+            np.testing.assert_array_equal(ch["p_end"], lay.attn_p_end[sl])
+            np.testing.assert_array_equal(ch["s_start"], lay.attn_s_start[sl])
+        assert 0 < granted <= max(eng.token_budget - n_decode, 1)
+        assert set(chunks) <= set(rows)
 
-    for a, b in zip(rag_reqs, pad_reqs):
-        assert a.out_tokens == b.out_tokens, (a.req_id, a.out_tokens, b.out_tokens)
-    # the packed layout actually removed padding work
-    assert rag_eng.stats()["padded_token_fraction"] < \
-        pad_eng.stats()["padded_token_fraction"]
+    # the counters the benchmark reads add up over the packed steps, and
+    # the packing stays below the chunk-width slab of every row
+    st = eng.stats()
+    assert st["fused_slot_tokens"] == slot_tokens < len(seen) * B * C
+    assert st["fused_valid_tokens"] == valid_tokens
 
 
 # --------------------------------------------------------- multi-turn sessions

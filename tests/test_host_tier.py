@@ -107,21 +107,21 @@ def test_swap_preemption_matches_unconstrained_oracle():
 
 def test_swap_and_recompute_token_identical_under_churn():
     """The two preemption strategies are interchangeable observationally:
-    identical greedy streams on a bursty mixed workload (interleaved and
-    sequential modes)."""
+    identical greedy streams on a bursty mixed workload (pipelined dispatch
+    and the eager sync oracle)."""
     cfg = _cfg()
     rng = np.random.default_rng(7)
     prompts = [rng.integers(0, 90, size=int(rng.integers(4, 24)))
                for _ in range(5)]
     outs = {}
-    for interleave in (True, False):
+    for pipeline in (True, False):
         for mode in ("recompute", "swap"):
-            eng = _pressure_engine(cfg, mode, interleave=interleave)
+            eng = _pressure_engine(cfg, mode, pipeline=pipeline)
             reqs = [eng.submit(p, max_new=18) for p in prompts]
             eng.run_until_done(max_steps=2000)
             assert all(r.done for r in reqs)
-            outs[(interleave, mode)] = [r.out_tokens for r in reqs]
-        assert outs[(interleave, "swap")] == outs[(interleave, "recompute")]
+            outs[(pipeline, mode)] = [r.out_tokens for r in reqs]
+        assert outs[(pipeline, "swap")] == outs[(pipeline, "recompute")]
 
 
 def test_swap_falls_back_to_recompute_when_host_tier_full():

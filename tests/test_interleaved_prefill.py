@@ -1,5 +1,6 @@
 """Sarathi-style interleaved chunked prefill: token-exact parity against the
-sequential-prefill oracle, per-step decode progress during long prefills,
+dense backend's whole-prompt prefill, per-step decode progress during long
+prefills,
 token-budget accounting, and the chunked-prefill TTFT cost-model term."""
 import numpy as np
 import pytest
@@ -30,21 +31,22 @@ def _prompts(seed: int, chunk: int):
     [(16, 20), (32, 36), (32, None)],  # None: default budget (max_batch + chunk)
 )
 def test_interleaved_matches_sequential_token_exact(chunk, budget):
-    """Greedy decode must be token-exact between interleaved and sequential
-    prefill, across chunk sizes and token budgets."""
+    """Greedy decode must be token-exact between interleaved chunked prefill
+    and the dense backend's sequential whole-prompt prefill, across chunk
+    sizes and token budgets."""
     cfg = _cfg()
     prompts = _prompts(seed=chunk, chunk=chunk)
     outs = {}
-    for interleave in (False, True):
+    for backend in ("dense", "paged"):
         eng = GenerationEngine(
             cfg, max_batch=3, max_seq=256, prefill_chunk_size=chunk,
-            token_budget=budget, interleave=interleave,
+            token_budget=budget, backend=backend,
         )
         reqs = [eng.submit(p, max_new=6) for p in prompts]
         eng.run_until_done()
         assert all(r.done for r in reqs)
-        outs[interleave] = [r.out_tokens for r in reqs]
-    assert outs[True] == outs[False]
+        outs[backend] = [r.out_tokens for r in reqs]
+    assert outs["paged"] == outs["dense"]
 
 
 def test_interleaved_matches_dense_oracle():
@@ -89,11 +91,11 @@ def test_decode_emits_every_step_during_long_prefill():
 
 
 def test_sequential_prefill_stalls_decode_oracle():
-    """Sanity on the A/B: with interleave=False the same workload DOES stall
-    the decode slot for the whole prefill (that is what interleaving fixes)."""
+    """Sanity on the A/B: the dense backend's sequential loop DOES stall the
+    decode slot for the whole prefill (that is what interleaving fixes)."""
     cfg = _cfg()
     eng = GenerationEngine(cfg, max_batch=2, max_seq=256, prefill_chunk_size=16,
-                           interleave=False)
+                           backend="dense")
     a = eng.submit(np.arange(5) % 90, max_new=40)
     for _ in range(3):
         eng.step()

@@ -126,13 +126,13 @@ def test_prefill_budget_grants_follow_policy_order():
 def test_admission_follows_policy_order():
     """A later-submitted lower-slack request must be admitted before an
     earlier higher-slack one under EDF (and after it under FIFO) — in both
-    the interleaved and the sequential-prefill admission paths."""
-    cases = (("edf_slack", True, "urgent"), ("fifo", True, "lax"),
-             ("edf_slack", False, "urgent"))
-    for scheduler, interleave, first in cases:
+    the paged planned loop and the dense backend's sequential admission."""
+    cases = (("edf_slack", "paged", "urgent"), ("fifo", "paged", "lax"),
+             ("edf_slack", "dense", "urgent"))
+    for scheduler, backend, first in cases:
         eng = GenerationEngine(
             _cfg(), max_batch=1, max_seq=128, scheduler=scheduler,
-            interleave=interleave,
+            backend=backend,
         )
         filler = eng.submit(np.arange(8) % 90, max_new=6, priority=0.0)
         eng.step()  # filler occupies the only slot
